@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
+from repro.index.base import DECODE_CHUNK
 from repro.kernels import ops, ref, tune
 from repro.kernels.rerank_dist import rerank_gather_dist_chunked_xla
 
@@ -78,8 +79,8 @@ def _bench_table(results, codes, queries, cand):
     default_l = tune.KERNELS["rerank_gather_dist.xla"].params["chunk_l"]
 
     vmap_fn = jax.jit(jax.vmap(
-        lambda qr, ci: jnp.sum(jnp.square(
-            ref.decode_with_table(codes[ci], table) - qr[None, :]), axis=-1),
+        lambda qr, ci: ref.sq_dist(
+            ref.decode_with_table(codes[ci], table), qr[None, :]),
         in_axes=(0, 0)))
     interp = ops._interpret()
 
@@ -156,7 +157,7 @@ def _bench_decoder(results, n, queries, cand):
 
     n_unique = int(np.unique(np.asarray(cand)).size)
     vm, dd = VmapRerank(), DedupRerank()
-    u_pad = -(-n_unique // dd.decode_chunk) * dd.decode_chunk
+    u_pad = -(-n_unique // DECODE_CHUNK) * DECODE_CHUNK
     paths = {
         "vmap/decoder": (lambda: vm.distances(index, queries, cand),
                          q * topl * _D * 4),

@@ -120,6 +120,8 @@ def main(argv=None) -> None:
                     help="semicolon-separated subset of smoke specs "
                          f"(known: {list(SMOKE_SPECS)})")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     if args.smoke:

@@ -29,7 +29,8 @@ from repro.utils.pytree import param_count
 def main():
     ckpt_dir = tempfile.mkdtemp(prefix="repro_dist_")
     cfg = configs.get("deepseek-moe-16b", smoke=True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = dict(shard_lib.RULES_SINGLE_POD)
     print(f"devices={len(jax.devices())} mesh={dict(mesh.shape)} "
           f"arch={cfg.name}")

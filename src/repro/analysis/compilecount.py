@@ -31,7 +31,8 @@ import re
 #: the raw-batch pad to the bucket, and the unpad slice back out
 _ADD_GLUE = ("concatenate", "_pad", "dynamic_slice", "convert_element_type")
 
-_NAME_RE = re.compile(r"Compiling ([\w.<>\-]+)")
+#: the computation's name; JAX logs it bare or as ``jit(<name>)``
+_NAME_RE = re.compile(r"Compiling (?:jit\()?([\w.<>\-]+)")
 
 
 class CompileLog:

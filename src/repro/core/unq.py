@@ -107,13 +107,18 @@ def _init_mlp(key, d_in: int, hidden: int, n_hidden: int, d_out: int, dtype):
 
 
 def _mlp_apply(params, state, x, *, train: bool, momentum: float):
+    """Inference (``train=False``: the LUT-forming encoder and the d1
+    decoder) runs its dots with full f32 passes, which a TPU's default
+    single bf16 pass would round; training keeps the default."""
+    precision = None if train else jax.lax.Precision.HIGHEST
     new_bn = []
     for lin, bn_p, bn_s in zip(params["layers"], params["bn"], state["bn"]):
-        x = x @ lin["w"] + lin["b"]
+        x = jnp.dot(x, lin["w"], precision=precision) + lin["b"]
         x, s = _bn_apply(bn_p, bn_s, x, train=train, momentum=momentum)
         new_bn.append(s)
         x = jax.nn.relu(x)
-    x = x @ params["head"]["w"] + params["head"]["b"]
+    x = jnp.dot(x, params["head"]["w"], precision=precision) \
+        + params["head"]["b"]
     return x, {"bn": new_bn}
 
 
@@ -154,8 +159,12 @@ def encode_heads(params, state, cfg: UNQConfig, x, *, train: bool):
 
 
 def head_logits(params, heads):
-    """Raw dot products ``<net(x)_m, c_mk>``: (B, M, d_c) -> (B, M, K)."""
-    return jnp.einsum("bmd,mkd->bmk", heads, params["codebooks"])
+    """Raw dot products ``<net(x)_m, c_mk>``: (B, M, d_c) -> (B, M, K).
+
+    Full f32 passes: these are the d2 tables and the encoder's argmax,
+    which a TPU's default single bf16 pass would round."""
+    return jnp.einsum("bmd,mkd->bmk", heads, params["codebooks"],
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def assignment_log_probs(params, heads):
@@ -206,7 +215,8 @@ def decode_from_onehot(params, state, cfg: UNQConfig, onehots, *, train: bool):
     The decoder input is the SUM over codebooks of the selected codewords
     ("the decoder adds the corresponding codewords", paper §3.2).
     """
-    z = jnp.einsum("bmk,mkd->bd", onehots, params["codebooks"])
+    z = jnp.einsum("bmk,mkd->bd", onehots, params["codebooks"],
+                   precision=jax.lax.Precision.HIGHEST)   # exact selection
     recon, new_state = _mlp_apply(params["decoder"], state["decoder"], z,
                                   train=train, momentum=cfg.bn_momentum)
     return recon, new_state

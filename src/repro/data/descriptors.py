@@ -75,6 +75,15 @@ def _sift_like(rng: np.random.Generator, n: int, dim: int, latent: int,
     return x.astype(np.float32)
 
 
+def _mixture(rng: np.random.Generator, dim: int, n_centers: int,
+             latent: int):
+    """The latent mixture: centers and the random two-layer feature map."""
+    centers = rng.normal(0, 1.0, (n_centers, latent))
+    w1 = rng.normal(0, 1.0 / np.sqrt(latent), (latent, 4 * latent))
+    w2 = rng.normal(0, 1.0 / np.sqrt(4 * latent), (4 * latent, dim))
+    return centers, w1, w2
+
+
 def make_synthetic_dataset(kind: str = "deep", *, dim: int | None = None,
                            n_train: int = 20_000, n_base: int = 50_000,
                            n_query: int = 1_000, n_centers: int = 512,
@@ -86,9 +95,7 @@ def make_synthetic_dataset(kind: str = "deep", *, dim: int | None = None,
     if dim is None:
         dim = 96 if kind == "deep" else 128
     rng = np.random.default_rng(seed)
-    centers = rng.normal(0, 1.0, (n_centers, latent))
-    w1 = rng.normal(0, 1.0 / np.sqrt(latent), (latent, 4 * latent))
-    w2 = rng.normal(0, 1.0 / np.sqrt(4 * latent), (4 * latent, dim))
+    centers, w1, w2 = _mixture(rng, dim, n_centers, latent)
     gen = _deep_like if kind == "deep" else _sift_like
     train = gen(rng, n_train, dim, latent, centers, w1, w2)
     base = gen(rng, n_base, dim, latent, centers, w1, w2)
@@ -107,7 +114,8 @@ def exact_knn(queries: np.ndarray, base: np.ndarray, k: int,
 
     @jax.jit
     def _knn(qb):
-        d = (jnp.sum(qb * qb, axis=1)[:, None] - 2.0 * qb @ base_j.T
+        d = (jnp.sum(qb * qb, axis=1)[:, None]
+             - 2.0 * jnp.dot(qb, base_j.T, precision=jax.lax.Precision.HIGHEST)
              + base_sq[None, :])
         _, idx = jax.lax.top_k(-d, k)
         return idx

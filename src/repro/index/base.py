@@ -51,6 +51,30 @@ from repro.kernels import tune
 _KINDS: dict[str, type["Index"]] = {}
 
 
+#: rows per call of the jitted stage-2 decoder (``Index.decode_rows``)
+DECODE_CHUNK = 512
+
+
+def per_query(fn, *rows) -> jax.Array:
+    """``fn(*rows)`` for the per-query dots (score tables, coarse
+    distances) over row-aligned arrays, computed on rows padded to whole
+    8-row tiles and sliced back.
+
+    This is part of the search contract, not a rounding fix: a query's
+    results must not depend on the batch it rides in, and the TPU
+    compiler lowers a dot with fewer rows than one f32 sublane tile (8)
+    as a different program, with another accumulation order, from one
+    with whole tiles. Served batches are whole tiles already (query
+    buckets are multiples of 8), so padding the rest gives a query
+    searched alone the lowering it gets inside a batch."""
+    n = rows[0].shape[0]
+    pad = (-n) % 8
+    if pad == 0:
+        return fn(*rows)
+    return fn(*[jnp.pad(r, ((0, pad),) + ((0, 0),) * (r.ndim - 1))
+                for r in rows])[:n]
+
+
 class Index(abc.ABC):
     """Abstract compressed-database index (see module docstring)."""
 
@@ -72,7 +96,6 @@ class Index(abc.ABC):
         self.backend = backend        # scan backend name or "auto"
         self._codes: jax.Array | None = None     # (N, M) uint8
         self._bias: jax.Array | None = None      # (N,) f32 or None
-        self._rerank_fn = None                   # cached jitted vmap stage 2
         self._decode_fn = None                   # cached jitted chunk decode
         self._exhaustive_fn = None               # cached jitted use_d2=False
         self._table_cache = None                 # cached decode table
@@ -374,30 +397,35 @@ class Index(abc.ABC):
         return reranker_for(self).distances(self, queries, cand)
 
     def _rerank_distances_vmap(self, queries, cand) -> jax.Array:
-        """The materialized stage-2 oracle: per-query gather + decode +
-        reduce under vmap, building the (Q, L, D) reconstruction. Ground
-        truth for every streaming reranker, and the path backends without
-        streaming capabilities use.
+        """The materialized stage-2 oracle: every candidate's code row
+        gathered and decoded (``decode_rows``), the (Q, L, D)
+        reconstruction built, d1 reduced by ``ref.sq_dist``. Ground truth
+        for every streaming reranker, and the path backends without
+        streaming capabilities use."""
+        from repro.index.rerank import _sq_dist
+        cand = jnp.asarray(cand)
+        recon = self.decode_rows(jnp.take(self._codes, cand.reshape(-1),
+                                          axis=0))
+        return _sq_dist(recon.reshape(cand.shape + (self.dim,)),
+                        jnp.asarray(queries, jnp.float32)[:, None, :])
 
-        The jitted kernel is cached on the instance (codes passed as an
-        argument, so ``add``/``with_codes`` don't invalidate it); anything
-        that swaps quantizer parameters must call ``_invalidate_caches``.
-        """
-        if self._rerank_fn is None:
-            def _one(codes, q, c_idx):
-                recon = self._reconstruct(codes[c_idx])    # (L, D)
-                return jnp.sum(jnp.square(recon - q[None, :]), axis=-1)
-
-            self._rerank_fn = jax.jit(jax.vmap(_one, in_axes=(None, 0, 0)))
-        return self._rerank_fn(self._codes, queries, cand)
-
-    def _chunk_decode_fn(self):
-        """Jitted fixed-shape ``codes -> reconstructions`` used by the
-        dedup reranker's batched unique-row decode (cached; dropped by
-        ``_invalidate_caches``)."""
+    def decode_rows(self, codes) -> jax.Array:
+        """(n, M) codes -> (n, D) reconstructions, decoded
+        ``DECODE_CHUNK`` rows per call of one jitted program (cached;
+        dropped by ``_invalidate_caches``). Every stage-2 decode goes
+        through here, so a row's reconstruction is the same bits whatever
+        else is decoded with it: a compiler picks its matmul tiling per
+        shape, and a neural decoder run at two batch sizes need not agree
+        bit for bit."""
         if self._decode_fn is None:
             self._decode_fn = jax.jit(self._reconstruct)
-        return self._decode_fn
+        n = codes.shape[0]
+        if n == 0:
+            return jnp.zeros((0, self.dim), jnp.float32)
+        padded = jnp.pad(codes, ((0, (-n) % DECODE_CHUNK), (0, 0)))
+        return jnp.concatenate(
+            [self._decode_fn(padded[s:s + DECODE_CHUNK])
+             for s in range(0, padded.shape[0], DECODE_CHUNK)])[:n]
 
     def _exhaustive_rerank_topk(self, queries, k: int):
         """``use_d2=False``: exact-d1 top-k over ALL codes, chunked over N
@@ -413,7 +441,6 @@ class Index(abc.ABC):
 
     def _invalidate_caches(self) -> None:
         """Drop compiled closures over quantizer params (after train/load)."""
-        self._rerank_fn = None
         self._decode_fn = None
         self._exhaustive_fn = None
         self._table_cache = None

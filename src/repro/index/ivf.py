@@ -97,14 +97,30 @@ _IMAX = np.iinfo(np.int32).max
 _INDEX_CAPACITY = object()
 
 
+#: rows assigned and encoded per step of ``IVFIndex.add``
+ADD_BLOCK = 1 << 17
+
+
+def _cat(parts):
+    """Concatenate per-block arrays (None stays None, one block as is)."""
+    if parts[0] is None:
+        return None
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
 def _plan_width(w: int) -> int:
     """Pad the ragged plan width to a small ladder so repeated searches
-    with similar probe sizes reuse one compiled scan."""
+    with similar probe sizes reuse one compiled scan: multiples of 8 up to
+    128, of 128 up to 1024, then eight steps an octave (at most an eighth
+    of the width is padding)."""
     if w <= 8:
         return 8
     if w <= 128:
         return -(-w // 8) * 8
-    return -(-w // 128) * 128
+    if w <= 1024:
+        return -(-w // 128) * 128
+    step = 1 << (w - 1).bit_length() - 4
+    return -(-w // step) * step
 
 
 class IVFIndex(base.Index):
@@ -144,7 +160,6 @@ class IVFIndex(base.Index):
         # residual-mode caches (dropped by _invalidate_caches)
         self._crosslut = None                    # (nlist, M, K) cross-LUT
         self._res_table = None                   # (M+1, K', D) stage-2 table
-        self._res_rerank_fn = None               # jitted residual vmap oracle
 
     # -- delegated quantizer primitives ------------------------------------
 
@@ -208,7 +223,6 @@ class IVFIndex(base.Index):
         self._assign_fn = None
         self._crosslut = None
         self._res_table = None
-        self._res_rerank_fn = None
         self._plan_cache = {}
 
     # -- residual machinery --------------------------------------------------
@@ -294,8 +308,9 @@ class IVFIndex(base.Index):
         if getattr(self, "_assign_fn", None) is None:
             self._assign_fn = jax.jit(
                 lambda x, c: jnp.sum(c * c, axis=1)[None, :]
-                - 2.0 * x @ c.T)
-        return self._assign_fn(xs, self.coarse)
+                - 2.0 * jnp.dot(x, c.T, precision=jax.lax.Precision.HIGHEST))
+        return base.per_query(lambda x: self._assign_fn(x, self.coarse),
+                              jnp.asarray(xs))
 
     def _probe_with_dists(self, queries, nprobe: int):
         """Clamped per-query top-``nprobe`` probe PLUS the coarse-distance
@@ -390,19 +405,13 @@ class IVFIndex(base.Index):
             raise RuntimeError(f"{type(self).__name__}.add before train()")
         xs = jnp.asarray(xs)
         n = xs.shape[0]
-        cells_dev = jnp.argmin(self._coarse_dists(xs), axis=1).astype(
-            jnp.int32)
+        # assign + encode ADD_BLOCK rows at a time: the (rows, nlist)
+        # coarse distances and the quantizer's (rows, M, K) encode
+        # distances stay bounded however large the add
+        parts = [self._assign_encode(xs[s:s + ADD_BLOCK])
+                 for s in range(0, max(n, 1), ADD_BLOCK)]
+        cells_dev, codes, bias = (_cat(list(v)) for v in zip(*parts))
         cells = np.asarray(cells_dev, np.int32)
-        enc_in = xs - jnp.take(self.coarse, cells_dev, axis=0) \
-            if self.residual else xs
-        bucket = self._encode_bucket(n)
-        xp = jnp.pad(enc_in, ((0, bucket - n), (0, 0))) if bucket != n \
-            else enc_in
-        codes = self._encode(xp)[:n]
-        bias = self._encode_bias(codes)
-        if self._exact_residual:
-            cross = self._cross_bias(codes, cells_dev)
-            bias = cross if bias is None else bias + cross
         old_n = self.ntotal
         ids = np.arange(old_n, old_n + n, dtype=np.int32)
         if self._codes is not None:
@@ -428,6 +437,23 @@ class IVFIndex(base.Index):
         self._pos_dev = jnp.asarray(pos)
         self._plan_cache = {}
         return self
+
+    def _assign_encode(self, xs):
+        """One block of ``add``: (cells (n,) i32, codes (n, M), bias (n,)
+        f32 or None) for the rows of xs."""
+        n = xs.shape[0]
+        cells = jnp.argmin(self._coarse_dists(xs), axis=1).astype(jnp.int32)
+        enc_in = xs - jnp.take(self.coarse, cells, axis=0) \
+            if self.residual else xs
+        bucket = self._encode_bucket(n)
+        xp = jnp.pad(enc_in, ((0, bucket - n), (0, 0))) if bucket != n \
+            else enc_in
+        codes = self._encode(xp)[:n]
+        bias = self._encode_bias(codes)
+        if self._exact_residual:
+            cross = self._cross_bias(codes, cells)
+            bias = cross if bias is None else bias + cross
+        return cells, codes, bias
 
     # -- probing -------------------------------------------------------------
 

@@ -42,7 +42,8 @@ class PQIndex(base.Index):
     def _build_luts(self, queries) -> jax.Array:
         # per-subspace squared-L2 tables; summed over m this is the exact
         # compressed-domain distance (no per-query constant needed)
-        return jax.vmap(self.model.lut)(queries)
+        return base.per_query(jax.vmap(self.model.lut),
+                              jnp.asarray(queries))
 
     def _build_decode_table(self) -> jax.Array:
         # each sub-codebook embedded into its D-slice (zero elsewhere);
@@ -54,7 +55,8 @@ class PQIndex(base.Index):
             table = table.at[i, :, i * d_sub:(i + 1) * d_sub].set(
                 self.model.codebooks[i])
         if self.model.rotation is not None:
-            table = table @ self.model.rotation.T
+            table = jnp.dot(table, self.model.rotation.T,
+                            precision=jax.lax.Precision.HIGHEST)
         return table
 
     def _reconstruct(self, codes) -> jax.Array:
@@ -146,7 +148,8 @@ class RVQIndex(base.Index):
     def _build_luts(self, queries) -> jax.Array:
         # scaling by -2 inside the table keeps scan scores bit-identical to
         # ``norms - 2 * adc_scan(codes, lut_ip)`` (x2 is exact in fp)
-        return -2.0 * jax.vmap(self.model.lut_ip)(queries)
+        return -2.0 * base.per_query(jax.vmap(self.model.lut_ip),
+                                     jnp.asarray(queries))
 
     def _build_decode_table(self) -> jax.Array:
         # additive codebooks are already full-dimensional

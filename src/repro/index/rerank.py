@@ -53,13 +53,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.index.backend import backend_supports, resolve_scan_backend
-from repro.kernels import ops
+from repro.index.base import DECODE_CHUNK
+from repro.kernels import ops, ref
 
 _IMAX = jnp.iinfo(jnp.int32).max
 
-#: decode-batch ladder for DedupRerank (fixed shapes -> one compile per
-#: bucket, smallest bucket >= the unique count serves small pools)
-DEDUP_DECODE_CHUNK = 2048
 #: L-chunk for the gathered-distance scan (shared with the table path)
 DEDUP_DIST_CHUNK = ops.DEFAULT_RERANK_CHUNK_L
 
@@ -111,6 +109,11 @@ class TableRerank(Reranker):
         return f"TableRerank(impl={self.impl!r})"
 
 
+@jax.jit
+def _sq_dist(recon, queries):
+    return ref.sq_dist(recon, queries)
+
+
 @functools.partial(jax.jit, static_argnames=("chunk_l",))
 def _gathered_dist_chunked(recon_u, queries, inv, *, chunk_l: int):
     """d[q, l] = ||queries[q] - recon_u[inv[q, l]]||^2 via a ``lax.scan``
@@ -123,8 +126,7 @@ def _gathered_dist_chunked(recon_u, queries, inv, *, chunk_l: int):
 
     def step(_, idx):
         recon = recon_u[idx]                                 # (Q, c, D)
-        return None, jnp.sum(jnp.square(recon - queries[:, None, :]),
-                             axis=-1)
+        return None, ref.sq_dist(recon, queries[:, None, :])
 
     _, ds = jax.lax.scan(step, None, inv_c)                  # (nc, Q, c)
     return jnp.moveaxis(ds, 0, 1).reshape(q, -1)[:, :l]
@@ -137,19 +139,19 @@ class DedupRerank(Reranker):
     appear in many top-L lists), so decoding ``codes[cand]`` per query
     repeats the expensive neural decode for every duplicate. This path
     runs host-side dedup on the concrete candidate matrix (search is
-    eager), decodes each unique code row ONCE in fixed-size batches, and
+    eager), decodes each unique code row ONCE (``Index.decode_rows``), and
     gathers the decoded rows back per (query, candidate) in chunks.
 
-    Memory: decoder activations are bounded by ``decode_chunk`` and the
-    gathered distance tiles by ``dist_chunk``; the held reconstruction is
-    the deduped (U, D) matrix, U = #unique <= min(Q*L, ntotal) — the
-    savings over the vmap path's (Q, L, D) scale exactly with the pool
-    overlap (worst case, fully disjoint pools, they are the same size).
+    Memory: decoder activations are bounded by ``base.DECODE_CHUNK`` rows
+    and the gathered distance tiles by ``dist_chunk``; the held
+    reconstruction is the deduped (U, D) matrix, U = #unique <=
+    min(Q*L, ntotal) — the savings over the vmap path's (Q, L, D) scale
+    exactly with the pool overlap (worst case, fully disjoint pools, they
+    are the same size).
 
-    Exactness: the decoder is row-stable (per-row results are independent
-    of batch composition for batch > 1), so gathered unique rows are
-    bit-identical to the per-query decode — d1 matches ``VmapRerank``
-    bit-for-bit.
+    Exactness: every row is decoded by the same compiled program as in
+    ``VmapRerank`` (``Index.decode_rows``) and reduced in the same
+    ``ref.sq_dist`` order, so d1 matches it bit-for-bit.
 
     ``add_centroid=True`` is the residual-IVF variant (resolved through
     ``ResidualRerank``): dedup runs over unique BUFFER ROWS — a row pins
@@ -160,10 +162,8 @@ class DedupRerank(Reranker):
 
     materializes_recon = False
 
-    def __init__(self, decode_chunk: int = DEDUP_DECODE_CHUNK,
-                 dist_chunk: int = DEDUP_DIST_CHUNK,
+    def __init__(self, dist_chunk: int = DEDUP_DIST_CHUNK,
                  add_centroid: bool = False):
-        self.decode_chunk = decode_chunk
         self.dist_chunk = dist_chunk
         self.add_centroid = add_centroid
 
@@ -171,24 +171,16 @@ class DedupRerank(Reranker):
         cand = jnp.asarray(cand)
         q, l = cand.shape
         uniq, inv = np.unique(np.asarray(cand), return_inverse=True)
-        # smallest ladder bucket >= n_unique (>= 8 keeps the decoder's
-        # matmuls off degenerate single-row shapes)
-        chunk = self.decode_chunk
-        while chunk // 2 >= max(uniq.size, 8) and chunk > 8:
-            chunk //= 2
-        pad = (-uniq.size) % chunk
-        rows_u = jnp.asarray(np.pad(uniq, (0, pad)), jnp.int32)
-        codes_u = jnp.take(index.codes, rows_u, axis=0)      # (U_pad, M)
-        cells_u = jnp.take(index._cells_dev, rows_u) \
-            if self.add_centroid else None
-        decode = index._chunk_decode_fn()
-        parts = []
-        for s in range(0, codes_u.shape[0], chunk):
-            r = decode(codes_u[s:s + chunk])
-            if cells_u is not None:
-                r = r + jnp.take(index.coarse, cells_u[s:s + chunk], axis=0)
-            parts.append(r)
-        recon_u = jnp.concatenate(parts, axis=0)
+        # pad the unique rows to a ladder (whole decode chunks, eight
+        # steps an octave) so pools of similar size share compiled shapes
+        u = uniq.size
+        step = max(DECODE_CHUNK, 1 << max(0, (u - 1).bit_length() - 3))
+        rows_u = jnp.asarray(np.pad(uniq, (0, -(-u // step) * step - u)),
+                             jnp.int32)
+        recon_u = index.decode_rows(jnp.take(index.codes, rows_u, axis=0))
+        if self.add_centroid:
+            recon_u = recon_u + jnp.take(
+                index.coarse, jnp.take(index._cells_dev, rows_u), axis=0)
         return _gathered_dist_chunked(
             recon_u, jnp.asarray(queries, jnp.float32),
             jnp.asarray(inv.reshape(q, l), jnp.int32),
@@ -251,19 +243,16 @@ class ResidualRerank(Reranker):
 
     @staticmethod
     def _vmap_residual(index, queries, cand):
-        """Materialized residual oracle: per-query gather + decode +
-        centroid add + reduce under vmap (cached on the index; dropped by
-        ``_invalidate_caches``)."""
-        if index._res_rerank_fn is None:
-            def _one(codes, cells, coarse, q, c_idx):
-                recon = index._reconstruct(codes[c_idx]) \
-                    + coarse[cells[c_idx]]                   # (L, D)
-                return jnp.sum(jnp.square(recon - q[None, :]), axis=-1)
-
-            index._res_rerank_fn = jax.jit(
-                jax.vmap(_one, in_axes=(None, None, None, 0, 0)))
-        return index._res_rerank_fn(index.codes, index._cells_dev,
-                                    index.coarse, queries, cand)
+        """Materialized residual oracle: every candidate row gathered,
+        decoded (``Index.decode_rows``) and given its centroid, the
+        (Q, L, D) reconstruction built, d1 reduced by ``ref.sq_dist``."""
+        cand = jnp.asarray(cand)
+        rows = cand.reshape(-1)
+        recon = index.decode_rows(jnp.take(index.codes, rows, axis=0)) \
+            + jnp.take(index.coarse, jnp.take(index._cells_dev, rows),
+                       axis=0)
+        return _sq_dist(recon.reshape(cand.shape + (index.dim,)),
+                        jnp.asarray(queries, jnp.float32)[:, None, :])
 
     def __repr__(self):
         return f"ResidualRerank({self.inner!r})"
@@ -340,8 +329,7 @@ def exhaustive_topk(reconstruct_fn, payload, queries, *, k: int,
         vals, idx = carry                                    # (Q, k) x2
         chunk, start = inp
         recon = reconstruct_fn(chunk)                        # (c, D), once
-        d = jnp.sum(jnp.square(recon[None, :, :] - queries[:, None, :]),
-                    axis=-1)                                 # (Q, c)
+        d = ref.sq_dist(recon[None, :, :], queries[:, None, :])  # (Q, c)
         gids = start + jnp.arange(chunk_n, dtype=jnp.int32)
         d = jnp.where(gids[None, :] < n, d, jnp.inf)
         cand_s = jnp.concatenate([vals, d], axis=1)

@@ -10,9 +10,10 @@ paper's billion-vector experiments across a pod.
 Placement modes:
 
   * ``device`` — the real thing: code (and bias) shards live RESIDENT on
-    devices under ``shard_map`` (``repro.parallel.search``), one shard per
-    device, per-device fused scan+top-L, all-gather of the (L, 2)
-    candidate tuples, one rerank on the merged pool. Selected by
+    devices under ``shard_map`` (``repro.parallel.search``), shard s on
+    device s (placed once, then reused), per-device fused scan+top-L,
+    all-gather of the (L, 2) candidate tuples, one rerank on the merged
+    pool. Selected by
     ``placement="auto"`` whenever more than one device is visible.
   * ``host`` — logical shards (host-side views over one code matrix),
     scanned sequentially. The single-device fallback, and what
@@ -64,6 +65,8 @@ class ShardedIndex:
         self.inner = inner
         self.num_shards = num_shards
         self.placement = placement
+        # device placement: (codes, bias) it was cut from -> PlacedShards
+        self._placed = None
         # explicit shard mode (from_shards): pre-split code blocks
         self._shards = None
         self._offsets = None
@@ -165,6 +168,30 @@ class ShardedIndex:
                           None if bias is None else bias[lo:hi]))
         return views
 
+    def _devices(self):
+        """Device placement runs shard s on device s."""
+        devices = jax.devices()[:self.num_shards]
+        if len(devices) < self.num_shards:
+            raise ValueError(
+                f"placement='device' with num_shards={self.num_shards} "
+                f"needs that many devices; {len(devices)} visible")
+        return devices
+
+    def _placed_shards(self, bias):
+        """The flat database resident as one shard per device, placed on
+        first use and reused while the inner codes and the stage-1 bias
+        stream are the same arrays (a shared filter mask lowers to a new
+        bias stream, and is placed for that call only)."""
+        from repro.parallel.search import place_shards
+        codes = self.inner.codes
+        if self._placed is not None and self._placed[0] is codes \
+                and self._placed[1] is bias:
+            return self._placed[2]
+        placed = place_shards(codes, bias, self._devices())
+        if bias is self.inner.bias:
+            self._placed = (codes, bias, placed)
+        return placed
+
     def _ivf_cell_bounds(self) -> list[int]:
         """Cell boundaries of the by-cell sharding: ``num_shards + 1``
         monotone cell ids cutting the cell-grouped buffer into row-balanced
@@ -212,8 +239,9 @@ class ShardedIndex:
                     f"scan backend, and {impl!r} does not declare it; use "
                     "placement='host' or a streaming backend (xla/pallas)")
             from repro.parallel.search import device_stage1_topl
-            return device_stage1_topl(self.inner.codes, luts, bias,
-                                      qbias=qbias, topl=topl, impl=impl)
+            placed = self._placed_shards(bias)
+            return device_stage1_topl(placed, luts, qbias=qbias, topl=topl,
+                                      impl=impl)
 
         gen = candidate_generator_for(self.inner.backend)
         all_scores, all_idx = [], []
@@ -291,7 +319,8 @@ class ShardedIndex:
                     shards.append((row_lo, row_hi, routing, ids, rowbias,
                                    qkeep, cellterm))
                 return device_dispatch_topl(ivf.codes, shards, luts,
-                                            topl=topl, impl=impl)
+                                            topl=topl, impl=impl,
+                                            devices=self._devices())
             from repro.parallel.search import device_gather_topl
             plans = []
             for s in range(self.num_shards):
@@ -305,7 +334,8 @@ class ShardedIndex:
                 slot_cells=cells if cell_bias is not None else None,
                 cell_bias=cell_bias)
             return device_gather_topl(ivf.codes, ivf.bias, plans, luts,
-                                      rowbias_fn, topl=topl, impl=impl)
+                                      rowbias_fn, topl=topl, impl=impl,
+                                      devices=self._devices())
 
         gen = candidate_generator_for(ivf.backend)
         pool_s, pool_i = [], []

@@ -96,7 +96,9 @@ class UNQIndex(base.Index):
                                impl=impl)
 
     def _build_luts(self, queries) -> jax.Array:
-        return build_luts(self.params, self.state, self.cfg, queries)
+        return base.per_query(
+            lambda q: build_luts(self.params, self.state, self.cfg, q),
+            jnp.asarray(queries))
 
     def _build_decode_table(self) -> None:
         # the MLP decoder is not an additive code table, so the stage-2

@@ -39,6 +39,7 @@ def _adc_scan_kernel(codes_ref, lut_ref, out_ref, *, block_n: int, num_books: in
         acc = acc + jax.lax.dot_general(
             onehot, lut[m].astype(jnp.float32),
             dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
     out_ref[...] = acc.astype(out_ref.dtype)
 
@@ -87,6 +88,7 @@ def _adc_scan_batch_kernel(codes_ref, luts_ref, out_ref, *, block_n: int,
         acc = acc + jax.lax.dot_general(
             luts[:, m, :].astype(jnp.float32), onehot,
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
     out_ref[...] = acc.astype(out_ref.dtype)
 

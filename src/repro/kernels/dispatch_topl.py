@@ -17,10 +17,11 @@ prefetched plan arrays drive data-dependent tile DMA without any gather.
 Memory model per grid step (grid = (T,), one step per tile, tiles of one
 cell consecutive):
 
-  * the (cap, L) score/id heap of the tile's cell lives in the OUTPUT
-    blocks, whose index map follows ``tile_e`` — consecutive tiles of one
-    cell map to the same block, so the heap stays VMEM-resident across the
-    cell's whole code range and is initialized when ``tile_first`` fires;
+  * the (cap, H) score/id heap (H = ``merge.heap_width(L)``) of the
+    tile's cell lives in the OUTPUT blocks, whose index map follows
+    ``tile_e`` — consecutive tiles of one cell map to the same block,
+    so the heap stays VMEM-resident across the cell's whole code range
+    and is initialized when ``tile_first`` fires;
   * the (chunk, M) uint8 code tile plus its (chunk,) global-id and
     row-bias streams flow HBM->VMEM addressed by ``tile_block`` — the
     codes are read IN PLACE from the cell-grouped buffer (no gathered
@@ -38,7 +39,7 @@ cell consecutive):
     gid ``_IMAX`` — identical bits to the gathered kernels' pad handling.
 
 Tie semantics are EXACTLY those of flat search: the in-kernel merge is the
-same shared bitonic (score asc, global id asc) pre-top-L merge
+same shared (score asc, global id asc) pre-top-L merge
 (``kernels/merge.py``) as ``gather_topl``, so per-cell partial top-Ls
 merged across cells
 (``index.dispatch.combine_pools`` -> ``candidates.merge_topl``) reproduce
@@ -66,6 +67,8 @@ from repro.kernels import merge
 DEFAULT_DISPATCH_CHUNK = 128
 
 _IMAX = jnp.iinfo(jnp.int32).max
+# one-hot contractions copy table entries exactly only with full f32 passes
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 class DispatchPlan(NamedTuple):
@@ -92,60 +95,59 @@ class DispatchPlan(NamedTuple):
 def _adc_dispatch_topl_kernel(tile_e_ref, tile_block_ref, tile_first_ref,
                               tile_lo_ref, tile_hi_ref, codes_ref, gid_ref,
                               rowb_ref, qidx_ref, cellterm_ref, luts_ref,
-                              *rest, topl: int, chunk: int, cap: int,
-                              num_q: int, num_books: int, book_size: int,
+                              *rest, chunk: int, cap: int, num_q: int,
+                              num_books: int, book_size: int,
                               has_qkeep: bool, has_scale: bool):
     rest = list(rest)
     qkeep_ref = rest.pop(0) if has_qkeep else None
     scale_ref = rest.pop(0) if has_scale else None
     scores_ref, idx_ref = rest
     t = pl.program_id(0)
+    heap_w = scores_ref.shape[-1]
 
     @pl.when(tile_first_ref[t] == 1)
     def _init():                  # fresh heap at the first tile of each cell
-        scores_ref[...] = jnp.full((1, cap, topl), jnp.inf, jnp.float32)
-        idx_ref[...] = jnp.full((1, cap, topl), _IMAX, jnp.int32)
+        scores_ref[...] = jnp.full((1, cap, heap_w), jnp.inf, jnp.float32)
+        idx_ref[...] = jnp.full((1, cap, heap_w), _IMAX, jnp.int32)
 
     # --- gather the cell's LUT batch: exact one-hot copy (one nonzero per
-    # row), so the routed (cap, M, K) tables never materialize in HBM ---
-    qidx = qidx_ref[...][0]                                    # (cap,)
+    # row), so the routed (cap, M*K) tables never materialize in HBM ---
+    qidx = qidx_ref[0]                                         # (cap, 1)
     iota_q = jax.lax.broadcasted_iota(jnp.int32, (cap, num_q), 1)
-    onehot_q = (qidx[:, None] == iota_q).astype(jnp.float32)   # (cap, Q)
+    onehot_q = (qidx == iota_q).astype(jnp.float32)            # (cap, Q)
     # quantized tables are f32-cast for the routing dot (an exact copy of
     # the f32-cast entries — one nonzero per row), so scoring below sees
     # exactly f32(qlut); a no-op for the default f32 tables
-    luts = luts_ref[...].astype(jnp.float32).reshape(
-        num_q, num_books * book_size)
-    lut_e = jax.lax.dot(onehot_q, luts,
-                        preferred_element_type=jnp.float32)
-    lut_e = lut_e.reshape(cap, num_books, book_size)
+    lut_e = jax.lax.dot(onehot_q, luts_ref[...].astype(jnp.float32),
+                        precision=_EXACT,
+                        preferred_element_type=jnp.float32)    # (cap, M*K)
     scale_e = None
     if has_scale:                      # routed copy of the int8 scales
-        scale_e = jax.lax.dot(onehot_q, scale_ref[...],
+        scale_e = jax.lax.dot(onehot_q, scale_ref[...], precision=_EXACT,
                               preferred_element_type=jnp.float32)  # (cap, M)
 
     # --- score the code tile once for the whole query batch: per-m one-hot
     # contraction, left-to-right m accumulation (adc_scan_ref chain); int8
     # scales multiply each per-m part BEFORE the chain (q_ref's order) ---
     codes = codes_ref[...].astype(jnp.int32)                   # (chunk, M)
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (book_size, chunk), 0)
-    acc = jnp.zeros((cap, chunk), jnp.float32)
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, book_size), 1)
+    acc = None
     for m in range(num_books):                                 # M is static
-        onehot_c = (codes[:, m][None, :] == iota_k).astype(jnp.float32)
-        part = jax.lax.dot(lut_e[:, m, :], onehot_c,
-                           preferred_element_type=jnp.float32)
+        onehot_c = (codes[:, m:m + 1] == iota_k).astype(jnp.float32)
+        part = jax.lax.dot_general(
+            lut_e[:, m * book_size:(m + 1) * book_size], onehot_c,
+            dimension_numbers=(((1,), (1,)), ((), ())), precision=_EXACT,
+            preferred_element_type=jnp.float32)                # (cap, chunk)
         if has_scale:
-            part = part * scale_e[:, m][:, None]
-        acc = acc + part
+            part = part * scale_e[:, m:m + 1]
+        acc = part if acc is None else acc + part
 
     # bias composition order is the padded path's _plan_rowbias order:
     # (row stream + per-(query, cell) term) added as ONE slot value, the
     # (Q, N) keep mask applied after — bit-identical for any stream mix
-    rowb = rowb_ref[...][0]                                    # (chunk,)
-    cellterm = cellterm_ref[...][0]                            # (cap,)
-    acc = acc + (rowb[None, :] + cellterm[:, None])
+    acc = acc + (rowb_ref[...] + cellterm_ref[0])              # (cap, chunk)
     if has_qkeep:
-        keep = jax.lax.dot(onehot_q, qkeep_ref[...],
+        keep = jax.lax.dot(onehot_q, qkeep_ref[...], precision=_EXACT,
                            preferred_element_type=jnp.float32)  # (cap, chunk)
         acc = jnp.where(keep > 0.5, acc, jnp.inf)
 
@@ -156,17 +158,17 @@ def _adc_dispatch_topl_kernel(tile_e_ref, tile_block_ref, tile_first_ref,
         jnp.int32, (1, chunk), 1)
     acc = jnp.where((grow >= tile_lo_ref[t]) & (grow < tile_hi_ref[t]),
                     acc, jnp.inf)
-    acc = jnp.where((qidx >= 0)[:, None], acc, jnp.inf)
-    gids = jnp.broadcast_to(gid_ref[...][0][None, :], (cap, chunk))
+    acc = jnp.where(qidx >= 0, acc, jnp.inf)
+    gids = jnp.broadcast_to(gid_ref[...], (cap, chunk))
     gids = jnp.where(acc == jnp.inf, _IMAX, gids)
 
-    # --- merge the tile into the cell's running heap: shared bitonic
-    # pre-top-L + merge (kernels/merge.py) — same tie semantics as
-    # gather_topl, so tie resolution is identical everywhere ---
+    # --- merge the tile into the cell's running heap: shared pre-top-L +
+    # merge (kernels/merge.py) — same tie semantics as gather_topl, so tie
+    # resolution is identical everywhere ---
     out_s, out_g = merge.merge_block_topl(
-        scores_ref[...][0], idx_ref[...][0], acc, gids, topl)
-    scores_ref[...] = out_s[None]
-    idx_ref[...] = out_g[None]
+        scores_ref[0], idx_ref[0], acc, gids, heap_w)
+    scores_ref[0] = out_s
+    idx_ref[0] = out_g
 
 
 @functools.partial(jax.jit, static_argnames=("topl", "chunk", "interpret"))
@@ -204,21 +206,26 @@ def adc_dispatch_topl_pallas(codes: jax.Array, gids_rows: jax.Array,
     t_b = plan.tile_e.shape[0]
     assert np_ % chunk == 0, f"N={np_} must be padded to a multiple of {chunk}"
     kernel = functools.partial(
-        _adc_dispatch_topl_kernel, topl=topl, chunk=chunk, cap=cap,
-        num_q=num_q, num_books=num_books, book_size=book_size,
+        _adc_dispatch_topl_kernel, chunk=chunk, cap=cap, num_q=num_q,
+        num_books=num_books, book_size=book_size,
         has_qkeep=qkeep is not None, has_scale=scale is not None)
+    # per-cell rows ride as (E+1, cap, 1) columns: a block of one cell is
+    # then a whole (cap, 1) tile, and the batch broadcasts along lanes
     in_specs = [
         pl.BlockSpec((chunk, num_books),
                      lambda t, te, tb, tf, tlo, thi: (tb[t], 0)),
         pl.BlockSpec((1, chunk), lambda t, te, tb, tf, tlo, thi: (0, tb[t])),
         pl.BlockSpec((1, chunk), lambda t, te, tb, tf, tlo, thi: (0, tb[t])),
-        pl.BlockSpec((1, cap), lambda t, te, tb, tf, tlo, thi: (te[t], 0)),
-        pl.BlockSpec((1, cap), lambda t, te, tb, tf, tlo, thi: (te[t], 0)),
-        pl.BlockSpec((num_q, num_books, book_size),
-                     lambda t, te, tb, tf, tlo, thi: (0, 0, 0)),
+        pl.BlockSpec((1, cap, 1),
+                     lambda t, te, tb, tf, tlo, thi: (te[t], 0, 0)),
+        pl.BlockSpec((1, cap, 1),
+                     lambda t, te, tb, tf, tlo, thi: (te[t], 0, 0)),
+        pl.BlockSpec((num_q, num_books * book_size),
+                     lambda t, te, tb, tf, tlo, thi: (0, 0)),
     ]
-    args = [codes, gids_rows[None, :], rowbias[None, :], plan.qidx,
-            cellterm, luts]
+    args = [codes, gids_rows[None, :], rowbias[None, :],
+            plan.qidx[:, :, None], cellterm[:, :, None],
+            luts.reshape(num_q, num_books * book_size)]
     if qkeep is not None:
         in_specs.append(pl.BlockSpec(
             (num_q, chunk), lambda t, te, tb, tf, tlo, thi: (0, tb[t])))
@@ -227,27 +234,29 @@ def adc_dispatch_topl_pallas(codes: jax.Array, gids_rows: jax.Array,
         in_specs.append(pl.BlockSpec(
             (num_q, num_books), lambda t, te, tb, tf, tlo, thi: (0, 0)))
         args.append(scale)
+    heap_w = merge.heap_width(topl)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(t_b,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, cap, topl),
+            pl.BlockSpec((1, cap, heap_w),
                          lambda t, te, tb, tf, tlo, thi: (te[t], 0, 0)),
-            pl.BlockSpec((1, cap, topl),
+            pl.BlockSpec((1, cap, heap_w),
                          lambda t, te, tb, tf, tlo, thi: (te[t], 0, 0)),
         ],
     )
-    return pl.pallas_call(
+    scores, ids = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((e1, cap, topl), jnp.float32),
-            jax.ShapeDtypeStruct((e1, cap, topl), jnp.int32),
+            jax.ShapeDtypeStruct((e1, cap, heap_w), jnp.float32),
+            jax.ShapeDtypeStruct((e1, cap, heap_w), jnp.int32),
         ],
         interpret=interpret,
     )(plan.tile_e, plan.tile_block, plan.tile_first, plan.tile_lo,
       plan.tile_hi, *args)
+    return scores[..., :topl], ids[..., :topl]
 
 
 @functools.partial(jax.jit, static_argnames=("topl", "chunk"))

@@ -4,15 +4,16 @@ IVF search scores a PER-QUERY slot list — the padded ragged batch built by
 concatenating the inverted lists of each query's probed cells — instead of
 the whole database. The flat streaming kernel (``topl_scan.py``) shares
 one (N, M) code block across all queries; here each query block carries
-its OWN gathered code tile, so the one-hot scoring contraction becomes a
-batched (per-query) MXU dot and everything else — the running (block_q, L)
+its OWN gathered code tile, so the one-hot scoring contraction runs once
+per query row of the block and everything else — the running (block_q, L)
 heap in VMEM, the lexicographic (score, global-id) merge, +inf masking of
 pad slots — is inherited unchanged.
 
 Memory model per grid step (grid = (Q/block_q, W/block_w), w innermost):
 
-  * the (block_q, L) score/id heap lives in the OUTPUT blocks, whose index
-    map ignores the w axis — VMEM-resident across the whole w sweep;
+  * the (block_q, H) score/id heap (H = ``merge.heap_width(L)``) lives in
+    the OUTPUT blocks, whose index map ignores the w axis — VMEM-resident
+    across the whole w sweep;
   * the (block_q, block_w, M) uint8 gathered-code tile, the (block_q,
     block_w) global-id tile and the (block_q, block_w) slot-bias tile
     stream HBM->VMEM (the gather itself happens outside the kernel: the
@@ -56,37 +57,43 @@ _IMAX = jnp.iinfo(jnp.int32).max
 
 
 def _adc_gather_topl_kernel(codes_ref, gids_ref, bias_ref, luts_ref,
-                            *refs, topl: int, block_w: int,
-                            block_q: int, num_books: int, book_size: int,
-                            has_scale: bool):
+                            *refs, block_q: int, num_books: int,
+                            book_size: int, has_scale: bool):
     refs = list(refs)
     scale_ref = refs.pop(0) if has_scale else None
     scores_ref, idx_ref = refs
     wi = pl.program_id(1)
+    heap_w = scores_ref.shape[-1]
 
     @pl.when(wi == 0)
     def _init():                      # fresh heap at the start of each w sweep
-        scores_ref[...] = jnp.full((block_q, topl), jnp.inf, jnp.float32)
-        idx_ref[...] = jnp.full((block_q, topl), _IMAX, jnp.int32)
+        scores_ref[...] = jnp.full((block_q, heap_w), jnp.inf, jnp.float32)
+        idx_ref[...] = jnp.full((block_q, heap_w), _IMAX, jnp.int32)
 
-    # --- score the gathered tile: per-query one-hot contraction, one
-    # batched MXU dot per codebook — the same per-m partial values (and
-    # the same left-to-right m accumulation) as the flat kernel, so a
-    # slot's score is bit-identical to the same point's flat score ---
-    codes = codes_ref[...].astype(jnp.int32)           # (Bq, Bw, M)
+    # --- score the gathered tile: per query row, per codebook, the one-hot
+    # contraction of the flat kernel — the same per-m partial values (and
+    # the same left-to-right m accumulation), so a slot's score is
+    # bit-identical to the same point's flat score. Each row's chain is
+    # selected into its row of the tile ---
     luts = luts_ref[...]                               # (Bq, M, K)
     scale = scale_ref[...] if has_scale else None      # (Bq, M)
-    acc = jnp.zeros((block_q, block_w), jnp.float32)
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, 1, book_size), 2)
-    for m in range(num_books):                         # M is static (8 or 16)
-        onehot = (codes[:, :, m:m + 1] == iota_k).astype(jnp.float32)
-        part = jax.lax.dot_general(
-            luts[:, m, :].astype(jnp.float32), onehot,
-            dimension_numbers=(((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        if has_scale:                  # int8: per-(query, book) scale on
-            part = part * scale[:, m][:, None]   # each part BEFORE the chain
-        acc = acc + part
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, book_size), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, bias_ref.shape, 0)
+    acc = jnp.zeros(bias_ref.shape, jnp.float32)       # (Bq, Bw)
+    for b in range(block_q):                           # Bq is static (8/16)
+        codes = codes_ref[b].astype(jnp.int32)         # (Bw, M)
+        chain = None
+        for m in range(num_books):                     # M is static (8 or 16)
+            onehot = (codes[:, m:m + 1] == iota_k).astype(jnp.float32)
+            part = jax.lax.dot_general(
+                luts[b, m:m + 1, :].astype(jnp.float32), onehot,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)    # (1, Bw)
+            if has_scale:              # int8: per-(query, book) scale on
+                part = part * scale[b:b + 1, m:m + 1]  # each part BEFORE
+            chain = part if chain is None else chain + part   # the chain
+        acc = jnp.where(row == b, chain, acc)
     acc = acc + bias_ref[...]
 
     # pad slots (gid == _IMAX) score +inf; +inf slots (filtered) get the
@@ -95,10 +102,10 @@ def _adc_gather_topl_kernel(codes_ref, gids_ref, bias_ref, luts_ref,
     acc = jnp.where(gids == _IMAX, jnp.inf, acc)
     gids = jnp.where(acc == jnp.inf, _IMAX, gids)
 
-    # --- merge tile into the running heap: shared bitonic pre-top-L +
-    # merge (kernels/merge.py) — identical tie semantics to topl_scan ---
+    # --- merge tile into the running heap: shared pre-top-L + merge
+    # (kernels/merge.py) — identical tie semantics to topl_scan ---
     out_s, out_g = merge.merge_block_topl(
-        scores_ref[...], idx_ref[...], acc, gids, topl)
+        scores_ref[...], idx_ref[...], acc, gids, heap_w)
     scores_ref[...] = out_s
     idx_ref[...] = out_g
 
@@ -130,8 +137,8 @@ def adc_gather_topl_pallas(gathered_codes: jax.Array, gids: jax.Array,
     assert 0 < topl <= w, (topl, w)
     grid = (q // block_q, w // block_w)
     kernel = functools.partial(
-        _adc_gather_topl_kernel, topl=topl, block_w=block_w, block_q=block_q,
-        num_books=num_books, book_size=book_size, has_scale=scale is not None)
+        _adc_gather_topl_kernel, block_q=block_q, num_books=num_books,
+        book_size=book_size, has_scale=scale is not None)
     in_specs = [
         pl.BlockSpec((block_q, block_w, num_books),
                      lambda qi, wi: (qi, wi, 0)),
@@ -145,20 +152,22 @@ def adc_gather_topl_pallas(gathered_codes: jax.Array, gids: jax.Array,
         in_specs.append(pl.BlockSpec((block_q, num_books),
                                      lambda qi, wi: (qi, 0)))
         operands.append(scale)
-    return pl.pallas_call(
+    heap_w = merge.heap_width(topl)
+    scores, idx = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((block_q, topl), lambda qi, wi: (qi, 0)),
-            pl.BlockSpec((block_q, topl), lambda qi, wi: (qi, 0)),
+            pl.BlockSpec((block_q, heap_w), lambda qi, wi: (qi, 0)),
+            pl.BlockSpec((block_q, heap_w), lambda qi, wi: (qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q, topl), jnp.float32),
-            jax.ShapeDtypeStruct((q, topl), jnp.int32),
+            jax.ShapeDtypeStruct((q, heap_w), jnp.float32),
+            jax.ShapeDtypeStruct((q, heap_w), jnp.int32),
         ],
         interpret=interpret,
     )(*operands)
+    return scores[:, :topl], idx[:, :topl]
 
 
 @functools.partial(jax.jit, static_argnames=("topl", "chunk_w"))

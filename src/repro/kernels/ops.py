@@ -26,14 +26,13 @@ the exact path's composition), and the exact lexicographic top-L of the
 pool is returned. ``lut_dtype='float32', overfetch=1`` — the default —
 routes down the literally unchanged bit-exact path.
 
-Off-TPU the Pallas kernels run in interpret mode automatically; CI can pin
-the decision with ``REPRO_PALLAS_INTERPRET=1`` (force interpret, e.g. when
-the accelerator probe is unreliable) or ``=0`` (force compiled).
+The Pallas kernels compile with Mosaic on a TPU and run in interpret mode
+everywhere else. Nothing can switch a TPU run to interpret mode: a timing
+or a parity check taken on the chip is always of the compiled kernel.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -69,10 +68,7 @@ def _on_tpu() -> bool:
 
 
 def _interpret() -> bool:
-    """Pallas interpret-mode decision, overridable for CI via env."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "")
-    if env != "":
-        return env not in ("0", "false", "False")
+    """Pallas interpret mode exactly when no TPU backs the kernels."""
     return not _on_tpu()
 
 
@@ -86,15 +82,28 @@ def _pad_to(x: jax.Array, multiple: int, axis: int = 0):
     return jnp.pad(x, widths), n
 
 
+def _onehot_scan(codes: jax.Array, luts: jax.Array) -> jax.Array:
+    """The MXU-shaped formulation: per codebook, a one-hot contraction
+    over K (one non-zero term, so an exact copy of the table entry at
+    full f32 passes), chained over M left to right like
+    ``ref.adc_scan_ref`` — the same bits as the oracle, whatever N."""
+    onehot = jax.nn.one_hot(codes.astype(jnp.int32), luts.shape[-1],
+                            dtype=luts.dtype)          # (N, M, K)
+    acc = None
+    for m in range(luts.shape[1]):
+        part = jnp.einsum("nk,qk->qn", onehot[:, m], luts[:, m],
+                          precision=jax.lax.Precision.HIGHEST)
+        acc = part if acc is None else acc + part
+    return acc
+
+
 def adc_scan(codes: jax.Array, lut: jax.Array, *, impl: str = "pallas",
              block_n: int | None = None) -> jax.Array:
     """scores[n] = sum_m lut[m, codes[n, m]].  codes (N, M), lut (M, K) -> (N,)."""
     if impl == "xla":
         return ref.adc_scan_ref(codes, lut)
     if impl == "onehot":
-        onehot = jax.nn.one_hot(codes.astype(jnp.int32), lut.shape[1],
-                                dtype=lut.dtype)          # (N, M, K)
-        return jnp.einsum("nmk,mk->n", onehot, lut)
+        return _onehot_scan(codes, lut[None])[0]
     if impl == "pallas":
         cfg = tune.best_config("adc_scan", "pallas", n=codes.shape[0])
         bn = cfg["block_n"] if block_n is None else block_n
@@ -117,9 +126,7 @@ def adc_scan_batch(codes: jax.Array, luts: jax.Array, *, impl: str = "pallas",
     if impl == "xla":
         return ref.adc_scan_batch_ref(codes, luts)
     if impl == "onehot":
-        onehot = jax.nn.one_hot(codes.astype(jnp.int32), luts.shape[-1],
-                                dtype=luts.dtype)      # (N, M, K)
-        return jnp.einsum("nmk,qmk->qn", onehot, luts)
+        return _onehot_scan(codes, luts)
     if impl == "pallas":
         q = luts.shape[0]
         cfg = tune.best_config("adc_scan_batch", "pallas",
@@ -261,7 +268,9 @@ def _gather_topl_run(codes, rows, gids, luts, scale, rowbias, *, topl: int,
         cfg = tune.best_config("adc_gather_topl", "pallas",
                                w=w, q=q, topl=topl)
         bq = tune.align(q, cap=cfg["block_q"] if block_q is None else block_q)
-        bw = tune.align(w, cap=cfg["block_w"] if block_w is None else block_w)
+        # whole 128-lane vregs: the in-kernel merge rolls along lanes
+        bw = tune.align(w, cap=cfg["block_w"] if block_w is None else block_w,
+                        multiple=128)
         gathered = jnp.take(codes, rows, axis=0)           # (Q, W, M) u8
         gathered, _ = _pad_to(gathered, bq, axis=0)
         gathered, _ = _pad_to(gathered, bw, axis=1)

@@ -40,6 +40,16 @@ def adc_scan_batch_ref(codes: jax.Array, luts: jax.Array) -> jax.Array:
     return jax.vmap(adc_scan_ref, in_axes=(None, 0))(codes, luts)
 
 
+def smallest(scores: jax.Array, k: int):
+    """The ``k`` smallest entries of each row, ascending, ties to the lower
+    position: ``-lax.top_k(-scores, k)`` as documented. Written as a
+    stable sort because on a TPU ``lax.top_k`` over 2^16 or more columns
+    lowers to a TopK custom call, which does not promise that tie order.
+    Returns (values, positions)."""
+    order = jnp.argsort(scores, axis=-1, stable=True)[..., :k]
+    return jnp.take_along_axis(scores, order, axis=-1), order
+
+
 def adc_scan_topl_ref(codes: jax.Array, luts: jax.Array,
                       bias: jax.Array | None, topl: int):
     """Materialized oracle for the streaming scan+top-L: the full (Q, N)
@@ -54,8 +64,7 @@ def adc_scan_topl_ref(codes: jax.Array, luts: jax.Array,
     scores = adc_scan_batch_ref(codes, luts)            # (Q, N)
     if bias is not None:
         scores = scores + bias[None, :]
-    neg, idx = jax.lax.top_k(-scores, min(topl, codes.shape[0]))
-    return -neg, idx
+    return smallest(scores, min(topl, codes.shape[0]))
 
 
 _IMAX = jnp.iinfo(jnp.int32).max
@@ -112,8 +121,8 @@ def adc_gather_topl_ref(codes: jax.Array, rows: jax.Array, gids: jax.Array,
         acc = acc + rowbias
     acc = jnp.where(gids == _IMAX, jnp.inf, acc)
     gids = jnp.where(jnp.isposinf(acc), _IMAX, gids)
-    neg, pos = jax.lax.top_k(-acc, min(topl, w))
-    return -neg, jnp.take_along_axis(gids, pos, axis=1)
+    s, pos = smallest(acc, min(topl, w))
+    return s, jnp.take_along_axis(gids, pos, axis=1)
 
 
 def adc_dispatch_topl_ref(codes: jax.Array, gids_rows: jax.Array,
@@ -176,8 +185,8 @@ def adc_dispatch_topl_ref(codes: jax.Array, gids_rows: jax.Array,
     acc = jnp.where((qidx >= 0)[..., None], acc, jnp.inf)
     gids = jnp.broadcast_to(gids_rows[None, None, :], acc.shape)
     gids = jnp.where(jnp.isposinf(acc), _IMAX, gids)
-    neg, pos = jax.lax.top_k(-acc, min(topl, n))
-    return -neg, jnp.take_along_axis(gids, pos, axis=-1)
+    s, pos = smallest(acc, min(topl, n))
+    return s, jnp.take_along_axis(gids, pos, axis=-1)
 
 
 def adc_scan_batch_q_ref(codes: jax.Array, qluts: jax.Array,
@@ -224,8 +233,7 @@ def adc_scan_topl_q_ref(codes: jax.Array, qluts: jax.Array,
         s = s + bias[None, :]
     if qbias is not None:
         s = s + qbias
-    neg, idx = jax.lax.top_k(-s, min(topl, codes.shape[0]))
-    return -neg, idx
+    return smallest(s, min(topl, codes.shape[0]))
 
 
 def adc_gather_topl_q_ref(codes: jax.Array, rows: jax.Array,
@@ -251,8 +259,8 @@ def adc_gather_topl_q_ref(codes: jax.Array, rows: jax.Array,
         acc = acc + rowbias
     acc = jnp.where(gids == _IMAX, jnp.inf, acc)
     gids = jnp.where(jnp.isposinf(acc), _IMAX, gids)
-    neg, pos = jax.lax.top_k(-acc, min(topl, w))
-    return -neg, jnp.take_along_axis(gids, pos, axis=1)
+    s, pos = smallest(acc, min(topl, w))
+    return s, jnp.take_along_axis(gids, pos, axis=1)
 
 
 def adc_dispatch_topl_q_ref(codes: jax.Array, gids_rows: jax.Array,
@@ -291,8 +299,8 @@ def adc_dispatch_topl_q_ref(codes: jax.Array, gids_rows: jax.Array,
     acc = jnp.where((qidx >= 0)[..., None], acc, jnp.inf)
     gids = jnp.broadcast_to(gids_rows[None, None, :], acc.shape)
     gids = jnp.where(jnp.isposinf(acc), _IMAX, gids)
-    neg, pos = jax.lax.top_k(-acc, min(topl, n))
-    return -neg, jnp.take_along_axis(gids, pos, axis=-1)
+    s, pos = smallest(acc, min(topl, n))
+    return s, jnp.take_along_axis(gids, pos, axis=-1)
 
 
 def decode_with_table(codes: jax.Array, table: jax.Array) -> jax.Array:
@@ -315,6 +323,50 @@ def decode_with_table(codes: jax.Array, table: jax.Array) -> jax.Array:
     return acc
 
 
+def fold_halves(x: jax.Array, axis: int = -1) -> jax.Array:
+    """Sum over ``axis`` in one fixed pairwise order, keeping the axis
+    (width 1): with P the power of two >= width and h = P / 2, element i
+    is added to element i + h (when that exists), and the halving
+    repeats until one element is left — the tree of a sum over the axis
+    zero-padded to P.
+
+    Every d1 path (the oracles, the chunked fallbacks, the dedup
+    reranker and the fused Pallas kernel) reduces ``(recon - q)^2`` in
+    this order, so their distances are the same bits on every platform
+    and for every batch shape. ``jnp.sum`` leaves the order to the
+    compiler, which picks it per shape and per device. The summands are
+    squares (never -0), so padding the axis with more zeros, as the
+    kernel does, leaves every partial sum unchanged.
+    """
+    axis = axis % x.ndim
+    w = x.shape[axis]
+    while w > 1:
+        h = 1 << (w - 1).bit_length() - 1              # P / 2
+        lo = jax.lax.slice_in_dim(x, 0, w - h, axis=axis)
+        hi = jax.lax.slice_in_dim(x, h, w, axis=axis)
+        x = jnp.concatenate(
+            [lo + hi, jax.lax.slice_in_dim(x, w - h, h, axis=axis)],
+            axis=axis)
+        w = h
+    return x
+
+
+def squares(diff: jax.Array) -> jax.Array:
+    """``diff^2`` as the summands of a d1 fold. The ``max(., 0)`` changes
+    no value (a square is >= 0); it keeps a compiler from contracting a
+    square and the first fold add into one fused multiply-add, which
+    rounds once where the definition rounds twice (XLA's CPU backend
+    does so inside fusions)."""
+    return jnp.maximum(jnp.square(diff), 0.0)
+
+
+def sq_dist(recon: jax.Array, q: jax.Array) -> jax.Array:
+    """Exact d1 (paper Eq. 7): ``||q - recon||^2`` over the last axis,
+    reduced in the ``fold_halves`` order. recon (..., D), q broadcastable
+    to it -> (...)."""
+    return fold_halves(squares(recon - q))[..., 0]
+
+
 def rerank_gather_dist_ref(cand_codes: jax.Array, queries: jax.Array,
                            table: jax.Array) -> jax.Array:
     """Materialized oracle for the fused gather-decode-distance kernel
@@ -328,7 +380,7 @@ def rerank_gather_dist_ref(cand_codes: jax.Array, queries: jax.Array,
     ground truth the streaming paths are validated against bit-for-bit.
     """
     recon = decode_with_table(cand_codes, table)         # (Q, L, D)
-    return jnp.sum(jnp.square(recon - queries[:, None, :]), axis=-1)
+    return sq_dist(recon, queries[:, None, :])
 
 
 def unq_encode_ref(heads: jax.Array, codebooks: jax.Array) -> jax.Array:

@@ -8,7 +8,8 @@ peak memory for stage 1 drops from O(Q*N) to O(Q*L).
 
 Memory model per grid step (grid = (Q/block_q, N/block_n), n innermost):
 
-  * the (block_q, L) score/index heap lives in the OUTPUT blocks, whose
+  * the (block_q, H) score/index heap (H = ``merge.heap_width(L)``, a
+    lane-aligned power of two >= L) lives in the OUTPUT blocks, whose
     index map ignores the n axis — Pallas keeps them in VMEM across the
     whole n sweep and writes them back to HBM once per query block;
   * the (block_n, M) uint8 code block and (block_n,) bias block stream in
@@ -19,8 +20,8 @@ Memory model per grid step (grid = (Q/block_q, N/block_n), n innermost):
 
 Tie semantics are EXACTLY those of ``lax.top_k`` over the full matrix:
 candidates are ordered by (score asc, global index asc). The merge is the
-shared bitonic pre-top-L of ``kernels/merge.py`` — block-local sort under
-the total lexicographic order, then one bitonic merge with the sorted
+shared pre-top-L of ``kernels/merge.py`` — block-local sort under
+the total lexicographic order, then one merge pass with the sorted
 heap — so the streaming result is bit-identical to the materialized oracle
 (``ref.adc_scan_topl_ref``), not merely set-equal. The same argument makes
 the chunked ``lax.scan`` fallback below exact: within the concatenated
@@ -45,7 +46,7 @@ _IMAX = jnp.iinfo(jnp.int32).max
 
 
 def _adc_scan_topl_kernel(codes_ref, luts_ref, bias_ref, *refs,
-                          topl: int, block_n: int, block_q: int,
+                          block_n: int, block_q: int,
                           num_books: int, book_size: int, n_valid: int,
                           has_qbias: bool, has_scale: bool):
     refs = list(refs)
@@ -53,11 +54,12 @@ def _adc_scan_topl_kernel(codes_ref, luts_ref, bias_ref, *refs,
     scale_ref = refs.pop(0) if has_scale else None
     scores_ref, idx_ref = refs
     ni = pl.program_id(1)
+    heap_w = scores_ref.shape[-1]
 
     @pl.when(ni == 0)
     def _init():                      # fresh heap at the start of each n sweep
-        scores_ref[...] = jnp.full((block_q, topl), jnp.inf, jnp.float32)
-        idx_ref[...] = jnp.full((block_q, topl), _IMAX, jnp.int32)
+        scores_ref[...] = jnp.full((block_q, heap_w), jnp.inf, jnp.float32)
+        idx_ref[...] = jnp.full((block_q, heap_w), _IMAX, jnp.int32)
 
     # --- score the streamed block: same one-hot MXU contraction as
     # adc_scan_batch (bit-identical scores, so ties resolve identically).
@@ -75,6 +77,7 @@ def _adc_scan_topl_kernel(codes_ref, luts_ref, bias_ref, *refs,
         part = jax.lax.dot_general(
             luts[:, m, :].astype(jnp.float32), onehot,
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         if has_scale:
             part = part * scale[:, m][:, None]
@@ -91,12 +94,12 @@ def _adc_scan_topl_kernel(codes_ref, luts_ref, bias_ref, *refs,
     acc = jnp.where(gids < n_valid, acc, jnp.inf)
     gids = jnp.broadcast_to(gids, (block_q, block_n))
 
-    # --- merge block into the running heap: block-local bitonic pre-top-L
-    # then one bitonic merge with the sorted heap (kernels/merge.py) —
+    # --- merge block into the running heap: block-local sort pre-top-L
+    # then one merge pass with the sorted heap (kernels/merge.py) —
     # compare/where ops only, bit-identical to the lexicographic
     # (score asc, global id asc) select it replaced ---
     out_s, out_g = merge.merge_block_topl(
-        scores_ref[...], idx_ref[...], acc, gids, topl)
+        scores_ref[...], idx_ref[...], acc, gids, heap_w)
     scores_ref[...] = out_s
     idx_ref[...] = out_g
 
@@ -136,7 +139,7 @@ def adc_scan_topl_pallas(codes: jax.Array, luts: jax.Array, bias: jax.Array,
     assert 0 < topl <= n_valid <= n, (topl, n_valid, n)
     grid = (q // block_q, n // block_n)
     kernel = functools.partial(
-        _adc_scan_topl_kernel, topl=topl, block_n=block_n, block_q=block_q,
+        _adc_scan_topl_kernel, block_n=block_n, block_q=block_q,
         num_books=num_books, book_size=book_size, n_valid=n_valid,
         has_qbias=qbias is not None, has_scale=scale is not None)
     in_specs = [
@@ -154,20 +157,22 @@ def adc_scan_topl_pallas(codes: jax.Array, luts: jax.Array, bias: jax.Array,
         in_specs.append(pl.BlockSpec((block_q, num_books),
                                      lambda qi, ni: (qi, 0)))
         operands.append(scale)
-    return pl.pallas_call(
+    heap_w = merge.heap_width(topl)
+    scores, idx = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((block_q, topl), lambda qi, ni: (qi, 0)),
-            pl.BlockSpec((block_q, topl), lambda qi, ni: (qi, 0)),
+            pl.BlockSpec((block_q, heap_w), lambda qi, ni: (qi, 0)),
+            pl.BlockSpec((block_q, heap_w), lambda qi, ni: (qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q, topl), jnp.float32),
-            jax.ShapeDtypeStruct((q, topl), jnp.int32),
+            jax.ShapeDtypeStruct((q, heap_w), jnp.float32),
+            jax.ShapeDtypeStruct((q, heap_w), jnp.int32),
         ],
         interpret=interpret,
     )(*operands)
+    return scores[:, :topl], idx[:, :topl]
 
 
 @functools.partial(jax.jit, static_argnames=("topl", "n_valid", "chunk_n"))

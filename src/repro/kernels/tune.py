@@ -104,11 +104,12 @@ KERNELS = {
     # one shared entry for both impls: the chunk is baked into the tile
     # plan by the router (index/dispatch.build_dispatch), so the router
     # and the kernel MUST resolve the same value — a single registry key
-    # guarantees it
+    # guarantees it. Chunks are whole 128-lane tiles: the kernel streams
+    # (1, chunk) row blocks, which Mosaic tiles in 128-lane units
     "adc_dispatch_topl": KernelSpec(
         {"chunk": DEFAULT_DISPATCH_CHUNK},
         ("n", "q"),
-        {"chunk": (64, 128, 256, 512)}),
+        {"chunk": (128, 256, 512, 1024)}),
     "rerank_gather_dist.pallas": KernelSpec(
         {"block_l": DEFAULT_RERANK_BLOCK_L,
          "block_q": DEFAULT_RERANK_BLOCK_Q},

@@ -27,6 +27,7 @@ def _unq_encode_kernel(heads_ref, books_ref, out_ref, *, num_books: int):
         scores = jax.lax.dot_general(
             heads[:, m, :], books[m],
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)   # (Bb, K)
         cols.append(jnp.argmax(scores, axis=-1).astype(jnp.int32))
     out_ref[...] = jnp.stack(cols, axis=1)        # (Bb, M)

@@ -16,7 +16,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 ("data", "model") single-pod or 2x16x16 ("pod","data","model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_elastic_mesh(devices=None, *, model_parallel: int | None = None):

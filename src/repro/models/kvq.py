@@ -164,9 +164,8 @@ def decode_attention_kvq_sharded(cfg: ModelConfig, cache, q, k_new, v_new,
         tuple(seq_axes)
     codes_spec = P(None, seq_spec, None, None)
     from repro.parallel import hints as _hints
-    from repro.utils.compat import shard_map as _shard_map
     with _hints.disabled():
-        out, k_codes, v_codes = _shard_map(
+        out, k_codes, v_codes = jax.shard_map(
             body, mesh=mesh,
             in_specs=(codes_spec, codes_spec, P(), P(), P(), P(), P(), P()),
             out_specs=(P(), codes_spec, codes_spec),

@@ -118,9 +118,8 @@ def moe_block_ep(p, cfg: ModelConfig, x, mesh):
         param_specs["shared"] = jax.tree.map(lambda _: P(), p["shared"])
 
     from repro.parallel import hints
-    from repro.utils.compat import shard_map
     with hints.disabled():   # no sharding constraints inside manual bodies
-        out, balance = shard_map(
+        out, balance = jax.shard_map(
             body, mesh=mesh,
             in_specs=(param_specs, P("data", "model", None)),
             out_specs=(P("data", "model", None), P()),

@@ -2,8 +2,9 @@
 under ``shard_map``, merged with an all-gather of (L, 2) candidate tuples.
 
 This is the pod-scale shape of the paper's billion-vector experiments: the
-uint8 code matrix (and RVQ-style bias) lives SHARDED across devices — no
-device ever holds the full database — each device runs the streaming
+uint8 code matrix (and RVQ-style bias) lives SHARDED across devices —
+``place_shards`` puts each contiguous row block on its own device once,
+and searches reuse the placement — each device runs the streaming
 scan+top-L engine over its own shard with replicated query LUTs, and the
 per-device (Q, L) score/index tuples are all-gathered so the host-side
 caller reranks ONE merged pool through the streaming stage-2 engine
@@ -51,13 +52,13 @@ candidate-tuple all-gather.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
-from repro.utils import compat
 
 _IMAX = np.iinfo(np.int32).max
 
@@ -84,7 +85,7 @@ def _device_topl_fn(mesh, topl_local: int, shard_rows: int, impl: str,
     in_specs = [P("shard"), P("shard"), P()]
     if has_qbias:
         in_specs.append(P(None, "shard"))
-    f = compat.shard_map(
+    f = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(), P()),
@@ -92,37 +93,71 @@ def _device_topl_fn(mesh, topl_local: int, shard_rows: int, impl: str,
     return jax.jit(f)
 
 
-def device_stage1_topl(codes, luts, bias, *, topl: int, impl: str,
-                       qbias=None, devices=None):
-    """Sharded stage 1 over ``devices`` (default: all local devices).
+class PlacedShards(NamedTuple):
+    """A flat database cut into one contiguous shard per device, each
+    shard resident on its own device (see ``place_shards``)."""
+    codes: jax.Array          # (D * shard_rows, M), sharded along rows
+    bias: jax.Array           # (D * shard_rows,) f32; +inf on pad rows
+    n: int                    # real rows (the rest is tail padding)
+    shard_rows: int
+    mesh: jax.sharding.Mesh
 
-    codes (N, M) uint8, luts (Q, M, K) f32, bias None | (N,),
-    qbias None | (Q, N) per-(query, point) bias stream (the lowered
-    filter mask), sharded along N alongside the codes ->
-    (scores, indices), each (Q, min(topl, N)), bit-identical to the flat
-    single-device search.
-    """
+
+def place_shards(codes, bias, devices=None) -> PlacedShards:
+    """Put row block s of ``codes`` (and ``bias``) on device s.
+
+    Each shard is sliced and transferred on its own, so no device ever
+    holds a padded copy of the whole database; the tail shard is padded
+    on its own device to the common row count, its pad rows carrying a
+    +inf bias so they can never surface (one SPMD program then serves
+    the ragged tail)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     devices = list(devices if devices is not None else jax.devices())
     d = len(devices)
-    n, _ = codes.shape
-    q = luts.shape[0]
-    topl = min(topl, n)
-
+    n, m = codes.shape
     shard_rows = -(-n // d)
-    pad = shard_rows * d - n
-    codes_p = jnp.pad(codes, ((0, pad), (0, 0)))
-    bias_full = bias if bias is not None else jnp.zeros((n,), jnp.float32)
-    # pad rows masked via +inf bias (uniform across devices, so one SPMD
-    # program handles the ragged tail shard)
-    bias_p = jnp.pad(bias_full.astype(jnp.float32), (0, pad),
-                     constant_values=jnp.inf)
-    args = [codes_p, bias_p, luts.astype(jnp.float32)]
+    bias = bias if bias is not None else jnp.zeros((n,), jnp.float32)
+    parts_c, parts_b = [], []
+    for s, dev in enumerate(devices):
+        lo, hi = min(s * shard_rows, n), min((s + 1) * shard_rows, n)
+        c = jax.device_put(codes[lo:hi], dev)
+        b = jax.device_put(bias[lo:hi].astype(jnp.float32), dev)
+        pad = shard_rows - (hi - lo)
+        if pad:
+            c = jnp.pad(c, ((0, pad), (0, 0)))
+            b = jnp.pad(b, (0, pad), constant_values=jnp.inf)
+        parts_c.append(c)
+        parts_b.append(b)
+    mesh = jax.sharding.Mesh(np.asarray(devices), ("shard",))
+    rows = NamedSharding(mesh, P("shard"))
+    return PlacedShards(
+        jax.make_array_from_single_device_arrays((d * shard_rows, m), rows,
+                                                 parts_c),
+        jax.make_array_from_single_device_arrays((d * shard_rows,), rows,
+                                                 parts_b),
+        n, shard_rows, mesh)
+
+
+def device_stage1_topl(placed: PlacedShards, luts, *, topl: int, impl: str,
+                       qbias=None):
+    """Sharded stage 1 over a database placed by ``place_shards``.
+
+    luts (Q, M, K) f32 (replicated), qbias None | (Q, N) per-(query,
+    point) bias stream (the lowered filter mask), sharded along N
+    alongside the codes -> (scores, indices), each (Q, min(topl, N)),
+    bit-identical to the flat single-device search.
+    """
+    d = placed.mesh.devices.size
+    q = luts.shape[0]
+    topl = min(topl, placed.n)
+    args = [placed.codes, placed.bias, luts.astype(jnp.float32)]
     if qbias is not None:
+        pad = d * placed.shard_rows - placed.n
         args.append(jnp.pad(qbias.astype(jnp.float32), ((0, 0), (0, pad))))
 
-    mesh = jax.sharding.Mesh(np.asarray(devices), ("shard",))
-    topl_local = min(topl, shard_rows)
-    fn = _device_topl_fn(mesh, topl_local, shard_rows, impl,
+    topl_local = min(topl, placed.shard_rows)
+    fn = _device_topl_fn(placed.mesh, topl_local, placed.shard_rows, impl,
                          qbias is not None)
     s_all, i_all = fn(*args)
 
@@ -145,7 +180,7 @@ def _device_gather_fn(mesh, topl_local: int, impl: str):
         return (jax.lax.all_gather(scores, "shard"),
                 jax.lax.all_gather(ids, "shard"))
 
-    f = compat.shard_map(
+    f = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P("shard"), P("shard"), P("shard"), P("shard"), P()),
         out_specs=(P(), P()),
@@ -238,7 +273,7 @@ def _device_dispatch_fn(mesh, topl_local: int, impl: str, has_qkeep: bool):
     in_specs = [P("shard")] * 12 + [P()]
     if has_qkeep:
         in_specs.append(P("shard"))
-    f = compat.shard_map(
+    f = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(), P()),

@@ -7,6 +7,11 @@ are bitwise-equal to each request searched alone, (b) zero deadline
 misses at quick scale under a generous budget, (c) warm-up recorded
 cold-compile lines so the timed trace never pays a jit. Non-zero exit
 on any drift.
+
+Both modes pin ``Scan(xla)``: they are CPU gates of the serving logic
+(batching, parity, deadlines) at toy sizes. The on-chip path — the
+``auto`` backend resolving to the compiled Pallas kernels — is driven by
+``chip_smoke.py`` at the repository root.
 """
 from __future__ import annotations
 
@@ -18,6 +23,7 @@ import numpy as np
 
 from repro.index import index_factory
 from repro.serve import ServeConfig, ServeEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 _DIM = 32
 
@@ -133,6 +139,7 @@ def main(argv=None) -> int:
     ap.add_argument("--rate", type=float, default=200.0,
                     help="demo arrival rate (req/s)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     return smoke() if args.smoke else demo(args.requests, args.rate)
 
 

@@ -203,7 +203,7 @@ def _make_rerank(impl):
 def _dispatch_impl() -> str:
     """The impl the dispatch sweep times: the compiled Pallas kernel on
     TPU, the xla stream everywhere interpret mode would apply."""
-    return "pallas" if (ops._on_tpu() and not ops._interpret()) else "xla"
+    return "pallas" if ops._on_tpu() else "xla"
 
 
 #: registry key -> (input builder, runner factory); the runner factory

@@ -31,6 +31,7 @@ import tempfile
 
 from repro.kernels import tune
 from repro.tune import FULL_BUCKETS, QUICK_BUCKETS, run_sweep
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _check_roundtrip(doc: dict, path: pathlib.Path) -> list[str]:
@@ -94,6 +95,7 @@ def main(argv=None) -> int:
                         help="timing repeats per candidate "
                              "(min-of-repeats; default 5)")
     args = parser.parse_args(argv)
+    enable_compile_cache()
     path = pathlib.Path(args.out) if args.out else tune.cache_path()
 
     if args.validate:

@@ -7,10 +7,8 @@ from repro.utils.pytree import (
     tree_cast,
 )
 from repro.utils.prng import PRNGSeq
-from repro.utils.compat import shard_map
 
 __all__ = [
-    "shard_map",
     "param_count",
     "param_bytes",
     "tree_flatten_with_names",

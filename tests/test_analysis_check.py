@@ -156,8 +156,7 @@ def test_compile_counter_sees_fresh_compiles_and_cache_hits():
 # ---------------------------------------------------------------------------
 
 def _run_cli(*args):
-    env = dict(os.environ, PYTHONPATH=str(REPO / "src"),
-               REPRO_PALLAS_INTERPRET="1")
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
     return subprocess.run(
         [sys.executable, "-m", "repro.analysis.check", *args],
         capture_output=True, text=True, env=env, cwd=REPO, timeout=570)

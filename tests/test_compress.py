@@ -62,7 +62,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.optim.compress import compressed_psum
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 mesh = jax.make_mesh((4,), ("d",))
 x = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.0

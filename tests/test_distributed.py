@@ -26,7 +26,8 @@ from repro.parallel import hints, sharding as shard_lib, steps as steps_lib
 
 assert len(jax.devices()) == 8
 cfg = configs.get("yi-6b", smoke=True)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = dict(shard_lib.RULES_SINGLE_POD)
 params_ps = shard_lib.params_pspecs(registry.logical_axes(cfg), rules)
 train_step, opt = steps_lib.make_train_step(cfg, lr_fn=optim.constant(1e-3))
@@ -68,7 +69,8 @@ batch = {"tokens": jnp.asarray(
 loss_single, _ = jax.jit(
     lambda p, b: registry.loss_fn(p, cfg, b))(params, batch)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = dict(shard_lib.RULES_SINGLE_POD)
 ps = shard_lib.params_pspecs(registry.logical_axes(cfg), rules)
 with mesh, hints.activation_sharding(rules, mesh):
@@ -95,7 +97,8 @@ from repro.checkpoint import CheckpointManager
 from repro.launch.mesh import make_elastic_mesh
 
 tmp = tempfile.mkdtemp()
-mesh8 = jax.make_mesh((2, 4), ("data", "model"))
+mesh8 = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 tree = {"w": jax.device_put(
     jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
     NamedSharding(mesh8, P("data", "model")))}
@@ -217,7 +220,8 @@ codes = jnp.asarray(rng.integers(0, 256, (4096, 8)), jnp.uint8)
 lut = jnp.asarray(rng.normal(size=(8, 256)), jnp.float32)
 single = ops.adc_scan(codes, lut, impl="xla")
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 codes_sh = jax.device_put(codes, NamedSharding(mesh, P("data", None)))
 lut_sh = jax.device_put(lut, NamedSharding(mesh, P()))
 with mesh:

@@ -249,7 +249,7 @@ def test_unq_index_matches_manual_two_stage_pipeline(tiny_unq, tiny_dataset):
 
     def rerank(cand_row, q_row):
         recon = unq.decode_codes(params, state, cfg, codes[cand_row])
-        d1 = jnp.sum(jnp.square(recon - q_row[None, :]), axis=-1)
+        d1 = ref.sq_dist(recon, q_row[None, :])
         neg1, order = jax.lax.top_k(-d1, 30)
         return cand_row[order]
 
@@ -363,3 +363,48 @@ def test_subset_view_restricts_results(tiny_dataset,
     assert int(np.asarray(got).max()) < half.ntotal
     # the view shares the quantizer: full index unchanged
     assert index.ntotal == tiny_dataset.base.shape[0]
+
+
+# ---------------------------------------------------------------------------
+# per-query math and stage 2 do not depend on the batch a query rides in
+# ---------------------------------------------------------------------------
+
+def test_per_query_runs_whole_8_row_tiles():
+    """``base.per_query`` hands ``fn`` whole 8-row tiles and slices the
+    result back: a one-query search and a served batch (query buckets are
+    multiples of 8) run the same row tiling of the table/coarse dots."""
+    from repro.index.base import per_query
+    seen = []
+
+    def fn(a, b):
+        seen.append((a.shape[0], b.shape[0]))
+        return a + b
+
+    x = jnp.arange(6, dtype=jnp.float32).reshape(3, 2)
+    out = per_query(fn, x, 2 * x)
+    assert seen == [(8, 8)]
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(3 * x))
+    seen.clear()
+    per_query(fn, jnp.ones((16, 2)), jnp.ones((16, 2)))
+    assert seen == [(16, 16)]
+
+
+@pytest.mark.parametrize("spec", ["UNQ", "IVF8,Residual,PQ4x32,Rerank50"])
+def test_single_query_search_matches_batched_bucket(spec, tiny_unq,
+                                                    tiny_dataset,
+                                                    trained_index_factory):
+    """Each query searched alone (Q=1) returns the bits it gets inside a
+    16-query batch: stage-1 tables, stage 2 decode (``decode_rows``) and
+    the d1 fold are batch-independent by construction."""
+    if spec == "UNQ":
+        cfg, params, state, _ = tiny_unq
+        index = UNQIndex.from_trained(params, state, cfg, rerank=60)
+        index.add(tiny_dataset.base)
+    else:
+        index = trained_index_factory(spec, iters=4)
+    queries = jnp.asarray(tiny_dataset.queries[:16])
+    d_b, i_b = index.search(queries, 10)
+    for j in (0, 5, 15):
+        d_1, i_1 = index.search(queries[j:j + 1], 10)
+        np.testing.assert_array_equal(np.asarray(d_1), np.asarray(d_b[j:j + 1]))
+        np.testing.assert_array_equal(np.asarray(i_1), np.asarray(i_b[j:j + 1]))

@@ -1,4 +1,4 @@
-"""Bitonic in-kernel merge helpers (``kernels/merge.py``) vs a lexsort
+"""In-kernel merge helpers (``kernels/merge.py``) vs a lexsort
 oracle: the block-local sort, the sorted-run merge, and the combined
 ``merge_block_topl`` fold must all be bit-identical to lexicographic
 (score asc, gid asc) selection — pads, ties and non-pow2 widths
@@ -54,7 +54,7 @@ def test_bitonic_sort_matches_lexsort(w, rows, tie_heavy, seed):
     order."""
     rng = np.random.default_rng(seed)
     s, g = _case(rng, (rows, w), tie_heavy=tie_heavy, pad_frac=0.15)
-    got_s, got_g = merge.bitonic_sort_pairs(s, g)
+    got_s, got_g = merge.sort_pairs(s, g)
     want_s, want_g = _oracle_sort(s, g)
     np.testing.assert_array_equal(np.asarray(got_s), want_s)
     np.testing.assert_array_equal(np.asarray(got_g), want_g)
@@ -96,7 +96,7 @@ def test_merge_sorted_pairs_matches_lexsort_prefix(heap_w, block_w, topl,
 def test_merge_block_topl_is_exact_fold(topl, block_w, tie_heavy, seed):
     """The kernels' actual step: a sorted (rows, topl) heap folded with an
     UNSORTED candidate block == lexsort top-L of heap + block. This is the
-    exactness claim of the whole bitonic upgrade."""
+    exactness claim of the in-kernel merge."""
     rng = np.random.default_rng(seed)
     hs, hg = _case(rng, (4, topl), tie_heavy=tie_heavy, pad_frac=0.3)
     hs, hg = _oracle_sort(hs, hg)
@@ -122,6 +122,6 @@ def test_all_pad_heap_and_degenerate_widths():
     np.testing.assert_array_equal(np.asarray(got_s)[:, 1:], hs[:, 1:])
     np.testing.assert_array_equal(np.asarray(got_g)[:, 1:], hg[:, 1:])
 
-    s1, g1 = merge.bitonic_sort_pairs(bs, bg)
+    s1, g1 = merge.sort_pairs(bs, bg)
     np.testing.assert_array_equal(np.asarray(s1), bs)
     np.testing.assert_array_equal(np.asarray(g1), bg)

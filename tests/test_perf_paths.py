@@ -58,7 +58,8 @@ params = registry.init(jax.random.PRNGKey(0), cfg)
 rng = np.random.default_rng(0)
 batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 17)),
                                jnp.int32)}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = dict(shard_lib.RULES_SINGLE_POD)
 ps = shard_lib.params_pspecs(registry.logical_axes(cfg), rules)
 with mesh, hints.activation_sharding(rules, mesh):

@@ -19,7 +19,7 @@ _SPEC = "PQ8x64,Rerank64"
 
 
 def _run_smoke(extra_env):
-    env = dict(os.environ, PYTHONPATH="src", REPRO_PALLAS_INTERPRET="1")
+    env = dict(os.environ, PYTHONPATH="src")
     env.pop("REPRO_SMOKE_FORCE_FAIL", None)
     env.update(extra_env)
     return subprocess.run(
