@@ -41,6 +41,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.manager import load_pytree, save_pytree
 from repro.index.backend import backend_supports, resolve_scan_backend
@@ -350,18 +351,22 @@ class Index(abc.ABC):
                     "(the exhaustive-rerank ablation scans every point)")
             return self._exhaustive_rerank_topk(queries, k)
         topl = min(self.rerank if use_rerank else k, self.ntotal)
-        luts = self._build_luts(queries)
+        with TraceAnnotation("index.lut_build"):
+            luts = self._build_luts(queries)
         gen = candidate_generator_for(self.backend)
         bias, qbias = self._lower_filter(filter_mask, queries.shape[0])
-        d2, cand = gen.topl(self._codes, luts, bias, topl=topl, qbias=qbias,
-                            lut_dtype=lut_dtype, overfetch=overfetch)
+        with TraceAnnotation("index.stage1"):
+            d2, cand = gen.topl(self._codes, luts, bias, topl=topl,
+                                qbias=qbias, lut_dtype=lut_dtype,
+                                overfetch=overfetch)
         if not use_rerank:
             d, i = d2[:, :k], cand[:, :k]
             if filter_mask is not None:
                 i = jnp.where(jnp.isposinf(d), -1, i)
             return d, i
         valid = jnp.isfinite(d2) if filter_mask is not None else None
-        return self._rerank_topk(queries, cand, k, valid=valid)
+        with TraceAnnotation("index.rerank"):
+            return self._rerank_topk(queries, cand, k, valid=valid)
 
     def _rerank_topk(self, queries, cand, k: int, *, valid=None):
         """Shared stage-2 tail: d1 rerank of the candidate pool + final
@@ -422,10 +427,11 @@ class Index(abc.ABC):
         n = codes.shape[0]
         if n == 0:
             return jnp.zeros((0, self.dim), jnp.float32)
-        padded = jnp.pad(codes, ((0, (-n) % DECODE_CHUNK), (0, 0)))
-        return jnp.concatenate(
-            [self._decode_fn(padded[s:s + DECODE_CHUNK])
-             for s in range(0, padded.shape[0], DECODE_CHUNK)])[:n]
+        with TraceAnnotation("rerank.decode"):
+            padded = jnp.pad(codes, ((0, (-n) % DECODE_CHUNK), (0, 0)))
+            return jnp.concatenate(
+                [self._decode_fn(padded[s:s + DECODE_CHUNK])
+                 for s in range(0, padded.shape[0], DECODE_CHUNK)])[:n]
 
     def _exhaustive_rerank_topk(self, queries, k: int):
         """``use_d2=False``: exact-d1 top-k over ALL codes, chunked over N

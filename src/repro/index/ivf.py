@@ -85,6 +85,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import annotate_function
 
 from repro.core.baselines import kmeans
 from repro.index import base
@@ -457,6 +458,7 @@ class IVFIndex(base.Index):
 
     # -- probing -------------------------------------------------------------
 
+    @functools.partial(annotate_function, name="ivf.probe_plan")
     def _probe_plan(self, probe: np.ndarray, cell_range=None,
                     row_offset: int = 0, probe_lens=None):
         """Concatenate the CSR inverted lists of each query's probed cells

@@ -47,10 +47,12 @@ from __future__ import annotations
 
 import abc
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.index.backend import backend_supports, resolve_scan_backend
 from repro.index.base import DECODE_CHUNK
@@ -60,6 +62,31 @@ _IMAX = jnp.iinfo(jnp.int32).max
 
 #: L-chunk for the gathered-distance scan (shared with the table path)
 DEDUP_DIST_CHUNK = ops.DEFAULT_RERANK_CHUNK_L
+
+
+class RerankMeter:
+    """Process-wide work counters of the dedup reranker: candidate slots
+    (Q * L entries of the stage-1 pools), the unique rows among them, and
+    the rows actually decoded once the unique rows are padded to the
+    compile ladder. ``repro.serve`` metrics read deltas of ``snapshot()``
+    since their ``reset()``, as they read ``dispatch.OVERFLOWS``."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._counts = (0, 0, 0)
+
+    def record(self, slots: int, unique: int, decoded: int) -> None:
+        with self._lock:
+            a, b, c = self._counts
+            self._counts = (a + slots, b + unique, c + decoded)
+
+    def snapshot(self) -> tuple[int, int, int]:
+        """(slots, unique rows, decoded rows) since process start."""
+        return self._counts
+
+
+#: process-wide rerank meter — ``DedupRerank.distances`` records here
+RERANK = RerankMeter()
 
 
 class Reranker(abc.ABC):
@@ -170,13 +197,16 @@ class DedupRerank(Reranker):
     def distances(self, index, queries, cand):
         cand = jnp.asarray(cand)
         q, l = cand.shape
-        uniq, inv = np.unique(np.asarray(cand), return_inverse=True)
+        # the host waits here for stage 1 to finish on the device
+        with TraceAnnotation("rerank.unique"):
+            uniq, inv = np.unique(np.asarray(cand), return_inverse=True)
         # pad the unique rows to a ladder (whole decode chunks, eight
         # steps an octave) so pools of similar size share compiled shapes
         u = uniq.size
         step = max(DECODE_CHUNK, 1 << max(0, (u - 1).bit_length() - 3))
-        rows_u = jnp.asarray(np.pad(uniq, (0, -(-u // step) * step - u)),
-                             jnp.int32)
+        padded = -(-u // step) * step
+        RERANK.record(q * l, u, padded)
+        rows_u = jnp.asarray(np.pad(uniq, (0, padded - u)), jnp.int32)
         recon_u = index.decode_rows(jnp.take(index.codes, rows_u, axis=0))
         if self.add_centroid:
             recon_u = recon_u + jnp.take(

@@ -61,8 +61,10 @@ class Request:
 
     ``deadline_ms`` is a latency budget relative to submission; the
     queue stamps ``t_submit`` and ``seq`` (FIFO tie-break) on submit and
-    derives the absolute ``t_deadline``. ``future`` resolves to this
-    request's own ``(distances, indices)`` numpy pair."""
+    derives the absolute ``t_deadline``; the engine stamps ``t_launch``
+    (same ``perf_counter`` clock) when the request's batch is launched,
+    so ``t_launch - t_submit`` is its queue wait. ``future`` resolves to
+    this request's own ``(distances, indices)`` numpy pair."""
     queries: np.ndarray                      # (q, dim) float32
     k: int
     nprobe: Any = None                       # None | int | (q,) int vector
@@ -72,6 +74,8 @@ class Request:
     t_submit: float = 0.0
     t_deadline: float | None = None
     seq: int = -1
+    # stamped by ServeEngine._execute
+    t_launch: float | None = None
     future: Any = None
 
     @property
@@ -89,6 +93,7 @@ class Batch(NamedTuple):
     nprobe: Any                              # None | int | (bucket,) vector
     filter_mask: np.ndarray | None           # None | (bucket, ntotal) bool
     deadline: float | None                   # earliest absolute deadline
+    seq: int = -1                            # engine's batch number
 
     @property
     def num_real(self) -> int:

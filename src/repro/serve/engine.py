@@ -14,6 +14,13 @@ interrupt so the wait for t+1's followers is cut the moment t finishes
 — which also keeps the observed service times (the scheduler's
 deadline-reserve EWMA) honest instead of folding linger into them.
 
+Tracing: the worker loop is tiled by ``jax.profiler.TraceAnnotation``
+spans (``serve.wait``, ``serve.linger``, ``serve.coalesce``,
+``serve.execute``, ``serve.result_wait``, ``serve.fanout``; the index
+nests its own inside ``serve.execute``); a batch's spans carry its
+number as ``batch=<seq>``. While no profiler records, a span costs about
+a microsecond. ``docs/SERVING.md`` lists them.
+
 Bit-parity contract: every result delivered through ``submit`` /
 ``search_requests`` is bitwise-equal to calling ``index.search`` on
 that request alone (ties included). ``batching`` documents why each
@@ -23,11 +30,13 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Any
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.index.base import Index
 from repro.index.ivf import IVFIndex, _INDEX_CAPACITY
@@ -83,6 +92,7 @@ class ServeEngine:
             linger_ms=self.config.linger_ms,
             deadline_slack_ms=self.config.deadline_slack_ms)
         self.metrics = ServeMetrics()
+        self._batch_seq = itertools.count()
         self._worker: threading.Thread | None = None
         self._worker_lock = threading.Lock()
 
@@ -174,15 +184,22 @@ class ServeEngine:
     # -- batch construction / execution ------------------------------------
 
     def _coalesce(self, requests) -> Batch:
-        return coalesce(
-            requests, ntotal=self.index.ntotal,
-            default_nprobe=None if self._ivf is None else self._ivf.nprobe,
-            pow2_k=self.config.pow2_k, buckets=self.config.query_buckets)
+        seq = next(self._batch_seq)
+        with TraceAnnotation("serve.coalesce", batch=seq):
+            return coalesce(
+                requests, ntotal=self.index.ntotal,
+                default_nprobe=None if self._ivf is None
+                else self._ivf.nprobe,
+                pow2_k=self.config.pow2_k,
+                buckets=self.config.query_buckets)._replace(seq=seq)
 
     def _execute(self, batch: Batch):
         """Launch the batched search; returns DEVICE arrays (JAX async
         dispatch pending) so the worker can overlap the next batch's
         host work before blocking on them."""
+        t_launch = time.perf_counter()
+        for r in batch.requests:
+            r.t_launch = t_launch
         cfg = self.config
         kw = dict(use_rerank=cfg.use_rerank, filter_mask=batch.filter_mask)
         if isinstance(self.index, IVFIndex):
@@ -193,7 +210,8 @@ class ServeEngine:
             kw.update(nprobe=batch.nprobe, use_dispatch=cfg.use_dispatch)
         else:
             kw.update(lut_dtype=cfg.lut_dtype, overfetch=cfg.overfetch)
-        return self.index.search(batch.queries, batch.k_eff, **kw)
+        with TraceAnnotation("serve.execute", batch=batch.seq):
+            return self.index.search(batch.queries, batch.k_eff, **kw)
 
     # -- warmup ------------------------------------------------------------
 
@@ -319,20 +337,22 @@ class ServeEngine:
         gap between "device done" and this call at poll granularity, so
         the EWMA tracks service, not linger."""
         try:
-            d_np, i_np = np.asarray(d), np.asarray(i)
+            with TraceAnnotation("serve.result_wait", batch=batch.seq):
+                d_np, i_np = np.asarray(d), np.asarray(i)
         except Exception as exc:             # noqa: BLE001
             for r in batch.requests:
                 if r.future is not None:
                     r.future.set_exception(exc)
             return
         t_done = time.perf_counter()
-        self.scheduler.observe_service((t_done - t0) * 1e3)
-        self.metrics.record_batch(batch)
-        parts = split_results(batch, d_np, i_np, self.index.ntotal)
-        for r, part in zip(batch.requests, parts):
-            self.metrics.record_request(r, t_done)
-            if r.future is not None:
-                r.future.set_result(part)
+        with TraceAnnotation("serve.fanout", batch=batch.seq):
+            self.scheduler.observe_service((t_done - t0) * 1e3)
+            self.metrics.record_batch(batch)
+            parts = split_results(batch, d_np, i_np, self.index.ntotal)
+            for r, part in zip(batch.requests, parts):
+                self.metrics.record_request(r, t_done)
+                if r.future is not None:
+                    r.future.set_result(part)
 
     def close(self, drain: bool = True) -> None:
         """Stop intake; with ``drain`` (default) the worker finishes

@@ -14,6 +14,8 @@ from __future__ import annotations
 import time
 from typing import Callable
 
+from jax.profiler import TraceAnnotation
+
 from repro.serve.queue import RequestQueue
 
 #: linger-wait slice (s) when an ``interrupt`` probe is armed: the
@@ -72,9 +74,16 @@ class Scheduler:
         linger wait; when it returns True the group is cut immediately —
         the engine uses it to stop a linger for batch t+1 from delaying
         fan-out of batch t once t's device result is ready."""
-        items = self.queue.take(self.max_batch_queries, block=block)
+        with TraceAnnotation("serve.wait"):
+            items = self.queue.take(self.max_batch_queries, block=block)
         if not items:
             return items
+        with TraceAnnotation("serve.linger"):
+            return self._linger(items, interrupt)
+
+    def _linger(self, items, interrupt):
+        """Refill ``items`` within the group's linger budget (the loop
+        ``next_items`` documents)."""
         used = sum(r.num_queries for r in items)
         cutoff = time.perf_counter() + self._linger_budget_s(items)
         while used < self.max_batch_queries:
