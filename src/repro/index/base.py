@@ -355,7 +355,11 @@ class Index(abc.ABC):
             luts = self._build_luts(queries)
         gen = candidate_generator_for(self.backend)
         bias, qbias = self._lower_filter(filter_mask, queries.shape[0])
-        with TraceAnnotation("index.stage1"):
+        bq = gen.query_block(self._codes.shape[0], queries.shape[0],
+                             topl=topl, lut_dtype=lut_dtype,
+                             overfetch=overfetch)
+        with TraceAnnotation("index.stage1",
+                             **({} if bq is None else {"block_q": bq})):
             d2, cand = gen.topl(self._codes, luts, bias, topl=topl,
                                 qbias=qbias, lut_dtype=lut_dtype,
                                 overfetch=overfetch)

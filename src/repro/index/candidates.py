@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import abc
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +53,26 @@ from repro.index.backend import backend_supports, resolve_scan_backend
 from repro.kernels import ops
 
 _IMAX = jnp.iinfo(jnp.int32).max
+
+
+class Stage1Meter:
+    """Process-wide count of the query blocks the fused stage-1 kernel
+    launched (grid rows: each sweeps every code once). ``repro.serve``
+    metrics read deltas of ``count`` since their ``reset()``, as they
+    read ``dispatch.OVERFLOWS``. Launches traced inside an outer jit
+    (the device-placed sharded path) are not counted."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.count = 0
+
+    def record(self, blocks: int) -> None:
+        with self._lock:
+            self.count += blocks
+
+
+#: process-wide stage-1 meter — ``StreamingTopL.topl`` records here
+STAGE1 = Stage1Meter()
 
 
 class CandidateGenerator(abc.ABC):
@@ -99,6 +120,13 @@ class CandidateGenerator(abc.ABC):
         raise NotImplementedError(
             f"{type(self).__name__} has no cell-batched dispatch face; "
             "gate callers on supports_dispatch(backend)")
+
+    def query_block(self, n: int, q: int, *, topl: int,
+                    lut_dtype: str = "float32",
+                    overfetch: int = 1) -> int | None:
+        """Queries the fused scan kernel scores per block for a ``topl``
+        call of Q queries over N codes; None when no fused kernel runs."""
+        return None
 
     def __repr__(self):
         return f"{type(self).__name__}(impl={self.impl!r})"
@@ -165,8 +193,22 @@ class StreamingTopL(CandidateGenerator):
 
     materializes_scores = False
 
+    def query_block(self, n: int, q: int, *, topl: int,
+                    lut_dtype: str = "float32",
+                    overfetch: int = 1) -> int | None:
+        if self.impl != "pallas":
+            return None
+        return ops.scan_topl_query_block(n, q, topl=topl,
+                                         lut_dtype=lut_dtype,
+                                         overfetch=overfetch)
+
     def topl(self, codes, luts, bias, *, topl: int, qbias=None,
              lut_dtype: str = "float32", overfetch: int = 1):
+        q = luts.shape[0]
+        bq = self.query_block(codes.shape[0], q, topl=topl,
+                              lut_dtype=lut_dtype, overfetch=overfetch)
+        if bq is not None:
+            STAGE1.record(-(-q // bq))
         return ops.adc_scan_topl(codes, luts, topl=topl, bias=bias,
                                  qbias=qbias, impl=self.impl,
                                  lut_dtype=lut_dtype, overfetch=overfetch)

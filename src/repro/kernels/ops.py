@@ -143,6 +143,30 @@ def adc_scan_batch(codes: jax.Array, luts: jax.Array, *, impl: str = "pallas",
     raise ValueError(f"unknown impl: {impl!r}")
 
 
+def _scan_topl_blocks(n: int, q: int, topl: int, block_n, block_q):
+    """(block_n, block_q) of the fused kernel for Q queries over N codes.
+    The query block is the batch rounded up to a sublane tile and capped
+    by the registry's block_q (128): a served bucket is ONE query block,
+    so each code block's one-hot is built and pushed into the MXU once
+    for all of its queries."""
+    cfg = tune.best_config("adc_scan_topl", "pallas", n=n, q=q, topl=topl)
+    bn = cfg["block_n"] if block_n is None else block_n
+    bq = tune.align(q, cap=cfg["block_q"] if block_q is None else block_q)
+    return bn, bq
+
+
+def scan_topl_query_block(n: int, q: int, *, topl: int,
+                          lut_dtype: str = "float32",
+                          overfetch: int = 1) -> int:
+    """The query block ``adc_scan_topl(impl="pallas")`` resolves for Q
+    queries over N codes at default blocks (what the ``index.stage1``
+    span and the stage-1 query-block counter report)."""
+    topl = min(topl, n)
+    if lut_dtype != "float32" or overfetch != 1:
+        topl = lut_quant.pool_width(topl, overfetch, n)
+    return _scan_topl_blocks(n, q, topl, None, None)[1]
+
+
 def _scan_topl_run(codes, luts, scale, bias, qbias, *, topl: int, impl: str,
                    block_n, block_q, chunk_n):
     """One streaming scan+top-L pass at the given table precision (the
@@ -156,9 +180,7 @@ def _scan_topl_run(codes, luts, scale, bias, qbias, *, topl: int, impl: str,
             codes, luts, bias, qbias, scale, topl=topl, n_valid=n,
             chunk_n=tune.clamp_chunk(n, cap=cn, floor=topl))
     if impl == "pallas":
-        cfg = tune.best_config("adc_scan_topl", "pallas", n=n, q=q, topl=topl)
-        bn = cfg["block_n"] if block_n is None else block_n
-        bq = tune.align(q, cap=cfg["block_q"] if block_q is None else block_q)
+        bn, bq = _scan_topl_blocks(n, q, topl, block_n, block_q)
         padded_codes, _ = _pad_to(codes, bn, axis=0)
         padded_luts, _ = _pad_to(luts, bq, axis=0)
         padded_bias, _ = _pad_to(bias.astype(jnp.float32), bn, axis=0)

@@ -13,8 +13,16 @@ Memory model per grid step (grid = (Q/block_q, N/block_n), n innermost):
     index map ignores the n axis — Pallas keeps them in VMEM across the
     whole n sweep and writes them back to HBM once per query block;
   * the (block_n, M) uint8 code block and (block_n,) bias block stream in
-    (double-buffered by the grid), are scored with the same one-hot MXU
-    contraction as ``adc_scan_batch``, and are merged into the heap;
+    (double-buffered by the grid) and are scored with the same one-hot MXU
+    contraction as ``adc_scan_batch``: each book's (block_n, K) one-hot is
+    built once per step and multiplied by all block_q LUT rows in one
+    ``dot_general``, so a whole served bucket (block_q up to 128) shares
+    it, into a (block_q, block_n) VMEM score scratch;
+  * the scores are merged into the heap one slab of query rows at a
+    time by a rolled loop: ``gcd(block_q, MERGE_ROWS)`` rows, so 16 for
+    the served buckets from 16 up and 8 for a block of 8 or 24. The merge
+    network is compiled once, at the size of one slab, whatever block_q
+    is;
   * rows past ``n_valid`` (the pad the wrapper added to reach a block_n
     multiple) are masked to +inf score so they can never surface.
 
@@ -31,15 +39,23 @@ among equal scores, and ``lax.top_k`` breaks ties by position.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import merge, ref
 
 DEFAULT_TOPL_BLOCK_N = 1024
-DEFAULT_TOPL_BLOCK_Q = 8
+#: the largest query block: a served bucket of up to 128 queries scores
+#: in one block (ops.py shrinks it to smaller batches)
+DEFAULT_TOPL_BLOCK_Q = 128
+#: query rows the merge folds at a time: two f32 sublane tiles. On a v5e
+#: a 128-query block scans 10^7 codes in 1.10 s with 16-row slabs and in
+#: 1.46 s with 8-row ones (PERF.md §6)
+MERGE_ROWS = 16
 DEFAULT_CHUNK_N = 4096
 
 _IMAX = jnp.iinfo(jnp.int32).max
@@ -52,7 +68,7 @@ def _adc_scan_topl_kernel(codes_ref, luts_ref, bias_ref, *refs,
     refs = list(refs)
     qbias_ref = refs.pop(0) if has_qbias else None
     scale_ref = refs.pop(0) if has_scale else None
-    scores_ref, idx_ref = refs
+    scores_ref, idx_ref, acc_ref = refs
     ni = pl.program_id(1)
     heap_w = scores_ref.shape[-1]
 
@@ -91,17 +107,27 @@ def _adc_scan_topl_kernel(codes_ref, luts_ref, bias_ref, *refs,
     # global ids of this block; pad rows (>= n_valid) masked to +inf score
     gids = ni * block_n + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_n), 1)                    # (1, Bn)
-    acc = jnp.where(gids < n_valid, acc, jnp.inf)
-    gids = jnp.broadcast_to(gids, (block_q, block_n))
+    acc_ref[...] = jnp.where(gids < n_valid, acc, jnp.inf)
+    slab = math.gcd(block_q, MERGE_ROWS)
+    gids = jnp.broadcast_to(gids, (slab, block_n))
 
-    # --- merge block into the running heap: block-local sort pre-top-L
+    # --- merge block into the running heap, one slab of query rows at a
+    # time (rows are independent): block-local sort pre-top-L
     # then one merge pass with the sorted heap (kernels/merge.py) —
     # compare/where ops only, bit-identical to the lexicographic
-    # (score asc, global id asc) select it replaced ---
-    out_s, out_g = merge.merge_block_topl(
-        scores_ref[...], idx_ref[...], acc, gids, heap_w)
-    scores_ref[...] = out_s
-    idx_ref[...] = out_g
+    # (score asc, global id asc) select it replaced. The loop stays
+    # rolled, so the program and the merge's vreg working set are those
+    # of one slab whatever block_q is ---
+    def merge_slab(r, carry):
+        rows = pl.ds(pl.multiple_of(r * slab, slab), slab)
+        out_s, out_g = merge.merge_block_topl(
+            scores_ref[rows, :], idx_ref[rows, :], acc_ref[rows, :], gids,
+            heap_w)
+        scores_ref[rows, :] = out_s
+        idx_ref[rows, :] = out_g
+        return carry
+
+    jax.lax.fori_loop(0, block_q // slab, merge_slab, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("topl", "n_valid", "block_n",
@@ -170,6 +196,7 @@ def adc_scan_topl_pallas(codes: jax.Array, luts: jax.Array, bias: jax.Array,
             jax.ShapeDtypeStruct((q, heap_w), jnp.float32),
             jax.ShapeDtypeStruct((q, heap_w), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((block_q, block_n), jnp.float32)],
         interpret=interpret,
     )(*operands)
     return scores[:, :topl], idx[:, :topl]

@@ -84,10 +84,16 @@ class KernelSpec(NamedTuple):
 #: through ``best_config`` (and picks up cached winners if a future
 #: sweep adds ladders).
 KERNELS = {
+    # block_q is a cap: ``ops`` shrinks it to the padded batch, so by
+    # default a served bucket (8 to 128 queries) scores in ONE query
+    # block and every code block's one-hot is built and pushed into the
+    # MXU once for the whole bucket; the merge folds the block in
+    # slabs of at most 16 rows whatever block_q is (``topl_scan``)
     "adc_scan_topl.pallas": KernelSpec(
         {"block_n": DEFAULT_TOPL_BLOCK_N, "block_q": DEFAULT_TOPL_BLOCK_Q},
         ("n", "q", "topl"),
-        {"block_n": (256, 512, 1024, 2048, 4096), "block_q": (8, 16)}),
+        {"block_n": (256, 512, 1024, 2048, 4096),
+         "block_q": (8, 16, 32, 64, 128)}),
     "adc_scan_topl.xla": KernelSpec(
         {"chunk_n": DEFAULT_CHUNK_N},
         ("n", "q", "topl"),

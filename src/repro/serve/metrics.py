@@ -2,7 +2,8 @@
 accounting, batching efficiency, cold-compile ledger, the dispatch-
 overflow counter (the load-shed events `index.dispatch.OVERFLOWS`
 rate-limits out of the warning stream — here they stay exactly
-countable), the dedup reranker's work (`index.rerank.RERANK`) and the
+countable), the dedup reranker's work (`index.rerank.RERANK`), the
+fused stage-1 kernel's query blocks (`index.candidates.STAGE1`) and the
 Python collector's pauses (`GC_PAUSES`, also traced as `host.gc` spans).
 """
 from __future__ import annotations
@@ -14,6 +15,7 @@ import time
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.index import candidates as _candidates
 from repro.index import dispatch as _dispatch
 from repro.index import rerank as _rerank
 
@@ -78,10 +80,10 @@ class ServeMetrics:
     a delta from this object's last ``reset()``, so concurrent direct
     index use outside the engine window doesn't pollute the count (two
     engines serving simultaneously would share it — overflow is a
-    property of the shared index, not of one queue). The rerank and
-    collector meters are read the same way, the collector's up to the
-    last completed batch: pauses after it (a caller's own clean-up, say)
-    delayed no request."""
+    property of the shared index, not of one queue). The rerank,
+    stage-1 and collector meters are read the same way, the collector's
+    up to the last completed batch: pauses after it (a caller's own
+    clean-up, say) delayed no request."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -101,6 +103,7 @@ class ServeMetrics:
             self.cold_compile_ms: dict[str, float] = {}
             self._overflow_base = _dispatch.OVERFLOWS.count
             self._rerank_base = _rerank.RERANK.snapshot()
+            self._stage1_base = _candidates.STAGE1.count
             self._gc_base = self._gc_last = GC_PAUSES.totals()
 
     @property
@@ -160,4 +163,6 @@ class ServeMetrics:
                                                         self._gc_base))
         return {"rerank_slots": slots, "rerank_unique_rows": unique,
                 "rerank_decoded_rows": decoded,
+                "stage1_query_blocks":
+                    _candidates.STAGE1.count - self._stage1_base,
                 "gc_pauses": pauses, "gc_pause_ms": ms}

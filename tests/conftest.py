@@ -11,6 +11,17 @@ import pytest
 jax.config.update("jax_enable_x64", False)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """Drop a module's compiled programs when it ends. XLA's CPU backend
+    keeps memory mappings for every executable it holds, and one test
+    process that runs several kernel-heavy modules (interpret-mode Pallas
+    kernels compile to large programs) would otherwise pass the kernel's
+    per-process map limit (``vm.max_map_count``) and die in a compile."""
+    yield
+    jax.clear_caches()
+
+
 @pytest.fixture(scope="session")
 def tiny_dataset():
     from repro.data.descriptors import make_synthetic_dataset

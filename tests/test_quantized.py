@@ -74,17 +74,20 @@ def test_exact_topl_tie_contract():
 # pool parity vs the *_q_ref oracles (both impls, both dtypes)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("block_q,q", [(8, 5), (16, 37), (24, 21),
+                                       (32, 29), (128, 125)])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("lut_dtype", ["float16", "int8"])
-def test_flat_pool_matches_q_ref(scan_case, impl, lut_dtype):
+def test_flat_pool_matches_q_ref(scan_case, impl, lut_dtype, block_q, q):
     rng = np.random.default_rng(3)
-    n, q, L = 700, 5, 33
+    n, L = 700, 33
     codes, luts = scan_case(rng, n, m=8, k=32, q=q, tie_heavy=True)
     bias = jnp.asarray(rng.integers(0, 3, (n,)), jnp.float32)
     qluts, scale = lut_quant.quantize_luts(luts, lut_dtype)
     want = ref.adc_scan_topl_q_ref(codes, qluts, scale, bias, L)
     got = ops._scan_topl_run(codes, qluts, scale, bias, None, topl=L,
-                             impl=impl, block_n=128, block_q=8, chunk_n=96)
+                             impl=impl, block_n=128, block_q=block_q,
+                             chunk_n=96)
     for w, g in zip(want, got):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 

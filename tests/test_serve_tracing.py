@@ -94,3 +94,36 @@ def test_forced_collection_counts_as_a_pause(engine, tmp_path):
     assert any(span["stats"].get("generation") == 2 for span in spans)
     gc.collect()                        # after the last batch: not counted
     assert engine.metrics.summary()["gc_pause_ms"] == s["gc_pause_ms"]
+
+
+def test_stage1_query_blocks_one_per_bucket(tmp_path):
+    """The fused stage-1 kernel scores a whole served bucket in one query
+    block: one block per 8-query and per 128-query batch, counted as a
+    delta that ``reset()`` clears, and the block on the stage-1 span."""
+    cfg = unq.UNQConfig(dim=16, num_codebooks=4, codebook_size=16,
+                        code_dim=8, hidden_dim=32)
+    params, state = unq.init(jax.random.PRNGKey(0), cfg)
+    codes = np.random.default_rng(0).integers(0, 16, (3000, 4), np.uint8)
+    index = UNQIndex.from_trained(params, state, cfg, codes=codes,
+                                  rerank=RERANK, backend="pallas")
+    eng = ServeEngine(index, ServeConfig(max_batch_queries=128))
+    try:
+        eng.warmup(buckets=(8, 128), ks=(8,))
+        eng.metrics.reset()
+        assert eng.metrics.summary()["stage1_query_blocks"] == 0
+        rng = np.random.default_rng(4)
+        with jax.profiler.trace(str(tmp_path)):
+            for rows in (128, 1, 128):
+                eng.submit(rng.standard_normal((rows, 16), np.float32),
+                           k=5).result(timeout=120)
+        blocks = [span["stats"].get("block_q")
+                  for span in host_spans(tmp_path, "index.stage1")]
+        assert sorted(blocks) == [8, 128, 128]
+        s = eng.metrics.summary()
+        assert s["batches"] == 3
+        assert s["real_queries"] + s["padded_queries"] == 128 + 8 + 128
+        assert s["stage1_query_blocks"] == 3
+        eng.metrics.reset()
+        assert eng.metrics.summary()["stage1_query_blocks"] == 0
+    finally:
+        eng.close()

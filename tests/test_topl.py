@@ -19,20 +19,28 @@ from repro.kernels import ops, ref
 # tables make d2 collisions ubiquitous, so parity tests exercise tie
 # RESOLUTION, not just score math
 
+#: (query block, queries) of the fused kernel: one 8-row merge slab, one
+#: 16-row slab, three 8-row slabs (24 is no multiple of 16), then blocks
+#: of 2 and 8 slabs of 16. No Q is a multiple of the slab height, and
+#: Q = 37 over 16-query blocks spans three blocks.
+QUERY_BLOCKS = [(8, 5), (16, 37), (24, 21), (32, 29), (128, 125)]
 
+
+@pytest.mark.parametrize("block_q,q", QUERY_BLOCKS)
 @pytest.mark.parametrize("tie_heavy", [False, True])
 @pytest.mark.parametrize("n,L", [(1000, 37),     # N % block_n != 0
                                  (257, 300),     # L > N (clamped to N)
                                  (2048, 64),     # exact block multiple
                                  (1, 1)])        # degenerate
-def test_topl_all_backends_bit_exact(scan_case, n, L, tie_heavy):
+def test_topl_all_backends_bit_exact(scan_case, n, L, tie_heavy, block_q, q):
     rng = np.random.default_rng(n + L)
-    codes, luts = scan_case(rng, n, m=8, k=64, q=5, tie_heavy=tie_heavy)
+    codes, luts = scan_case(rng, n, m=8, k=64, q=q, tie_heavy=tie_heavy)
     want_s, want_i = ref.adc_scan_topl_ref(codes, luts, None, L)
-    assert want_s.shape == (5, min(L, n))
+    assert want_s.shape == (q, min(L, n))
     for impl in ("xla", "pallas"):
         got_s, got_i = ops.adc_scan_topl(codes, luts, topl=L, impl=impl,
-                                         block_n=256, block_q=8, chunk_n=192)
+                                         block_n=256, block_q=block_q,
+                                         chunk_n=192)
         np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s),
                                       err_msg=impl)
         np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i),
@@ -119,12 +127,13 @@ def test_backend_capability_matrix_and_generator_resolution():
     assert not auto.materializes_scores
 
 
-def test_qbias_stream_flows_through_every_path(scan_case):
+@pytest.mark.parametrize("block_q,q", QUERY_BLOCKS)
+def test_qbias_stream_flows_through_every_path(scan_case, block_q, q):
     """The per-(query, point) bias stream (the lowered filter mask) is
     bit-exact across the materialized oracle, the chunked xla path and
     the fused kernel — ±inf entries included."""
     rng = np.random.default_rng(7)
-    n, q = 900, 5
+    n = 900
     codes, luts = scan_case(rng, n, m=4, k=32, q=q, tie_heavy=True)
     bias = jnp.asarray(rng.integers(0, 3, (n,)), jnp.float32)
     qbias = jnp.where(jnp.asarray(rng.integers(0, 3, (q, n))) == 0,
@@ -135,7 +144,8 @@ def test_qbias_stream_flows_through_every_path(scan_case):
     for impl in ("xla", "pallas"):
         got_s, got_i = ops.adc_scan_topl(codes, luts, topl=60, bias=bias,
                                          qbias=qbias, impl=impl,
-                                         block_n=256, chunk_n=192)
+                                         block_n=256, block_q=block_q,
+                                         chunk_n=192)
         np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s),
                                       err_msg=impl)
         np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i),

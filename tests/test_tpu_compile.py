@@ -57,14 +57,21 @@ def _compile(sharding, fn, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("m", [8, 16])
-def test_adc_scan_topl_compiles(v5e_chip, m):
+@pytest.mark.parametrize("m,block_q,lut_dtype", [
+    (8, 8, jnp.float32), (16, 8, jnp.float32),
+    # a full served bucket in one query block, merged in 16-row slabs
+    (8, 128, jnp.float32), (16, 128, jnp.float32), (8, 128, jnp.int8)])
+def test_adc_scan_topl_compiles(v5e_chip, m, block_q, lut_dtype):
     n = 1 << 20
-    _compile(v5e_chip,
-             lambda c, lut, b: adc_scan_topl_pallas(c, lut, b, topl=L,
-                                                    n_valid=n - 7),
-             ((n, m), jnp.uint8), ((8, m, K), jnp.float32),
-             ((n,), jnp.float32))
+    shapes = [((n, m), jnp.uint8), ((block_q, m, K), lut_dtype),
+              ((n,), jnp.float32)]
+    if lut_dtype == jnp.int8:          # int8 tables carry their scales
+        shapes.append(((block_q, m), jnp.float32))
+
+    def scan(c, lut, b, *scale):
+        return adc_scan_topl_pallas(c, lut, b, None, *scale, topl=L,
+                                    n_valid=n - 7, block_q=block_q)
+    _compile(v5e_chip, scan, *shapes)
 
 
 def test_adc_gather_topl_compiles(v5e_chip):
